@@ -63,8 +63,9 @@ TUNED_TABLE = ROOT / "src" / "repro" / "configs" / "paged_attn_tuned.json"
 
 SIZE = 1 << 16  # 64k-element block (fused_lans)
 
-# paged-attention decode workload: 8 slots, ring 256 in 16-row blocks
-PA_SHAPE = dict(B=8, h=8, n_kv=2, hd=64, bs=16, nb=16)
+# paged-attention decode workload: 8 slots, ring 256 in 16-row blocks;
+# head_dim 128 fills the 128-lane tile (tile_alignment_problems)
+PA_SHAPE = dict(B=8, h=8, n_kv=2, hd=128, bs=16, nb=16)
 
 # fused-model gate: bytes over the unavoidable K/V reads must stay under
 FUSED_RATIO_LIMIT = 1.1

@@ -7,7 +7,7 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import HBM_BYTES
+from repro.launch.mesh import chip_peaks
 
 
 def load_records(out_dir="experiments/dryrun"):
@@ -63,7 +63,7 @@ def run():
                 if r["status"] == "ok" and r["mesh"] == "pod1"
                 and r["memory_analysis"].get("temp_size_in_bytes", 0)
                 + r["memory_analysis"].get("argument_size_in_bytes", 0)
-                > HBM_BYTES]
+                > chip_peaks(r["device_kind"]).hbm_bytes]
         rows.append(("roofline/over_hbm_pod1", 0.0,
                      over if over else "all fit 16GiB"))
     ok = err_count == 0 and ok_count > 0

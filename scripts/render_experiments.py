@@ -7,7 +7,7 @@ import sys
 sys.path.insert(0, "src")
 
 from benchmarks.roofline_report import load_records  # noqa: E402
-from repro.launch.mesh import HBM_BYTES  # noqa: E402
+from repro.launch.mesh import chip_peaks  # noqa: E402
 
 BEGIN_R, END_R = "<!-- roofline:begin -->", "<!-- roofline:end -->"
 BEGIN_F, END_F = "<!-- fit:begin -->", "<!-- fit:end -->"
@@ -45,7 +45,8 @@ def fit_md(recs, mesh="pod1"):
         m = r["memory_analysis"]
         tot = (m.get("temp_size_in_bytes", 0)
                + m.get("argument_size_in_bytes", 0)) / 2**30
-        fits = "yes" if tot * 2**30 <= HBM_BYTES else "**no**"
+        hbm = chip_peaks(r["device_kind"]).hbm_bytes
+        fits = "yes" if tot * 2**30 <= hbm else "**no**"
         lines.append(f"| {r['arch']}/{r['shape']} | {tot:.1f} | {fits} |")
     return "\n".join(lines)
 

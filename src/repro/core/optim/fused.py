@@ -2,8 +2,9 @@
 
 Drop-in replacement for `lans(...)` / `lamb(...)` that routes every block
 through the 3-phase Pallas pipeline (repro.kernels.ops). This is the TPU
-analogue of the paper's `fused_lans` apex optimizer. On this CPU container
-the kernels run in interpret mode; on TPU pass interpret=False.
+analogue of the paper's `fused_lans` apex optimizer. The kernels compile on a
+TPU and run in interpret mode elsewhere (`interpret=None`, see
+`repro.kernels.default_interpret`).
 """
 from __future__ import annotations
 
@@ -35,7 +36,7 @@ def _make_fused(step_fn, needs_clip: bool):
         weight_decay: float = 0.01,
         decay_mask: Optional[Callable[[str], bool]] = None,
         grad_clip_norm: Optional[float] = 1.0,
-        interpret: bool = True,
+        interpret=None,
     ) -> GradientTransformation:
         mask_fn = decay_mask or WeightDecayMask()
         sched = learning_rate if callable(learning_rate) else (

@@ -12,6 +12,7 @@ Authoring conventions (interpret-mode default, block-spec patterns, how
 ref.py gates correctness, benchmark wiring) are documented in
 docs/kernels.md.
 """
+import jax
 
 # Shared attention-mask value: large but FINITE negative, so masked-lane
 # arithmetic underflows to exactly 0 (exp(NEG_INF - m) == 0) instead of
@@ -20,3 +21,13 @@ docs/kernels.md.
 # models/attention.py must underflow identically for the bit-exact
 # paged-pallas == paged-xla greedy-token contract to hold.
 NEG_INF = -2.3819763e38
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode unless running on a TPU.
+
+    Every kernel wrapper takes ``interpret=None`` and resolves it here, so
+    a TPU always runs the compiled kernels and the CPU backend always
+    interprets them; passing True or False overrides the backend.
+    """
+    return jax.default_backend() != "tpu"

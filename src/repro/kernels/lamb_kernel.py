@@ -19,7 +19,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lans_kernel import LANES, TILE_ROWS, _guarded_inv
+from repro.kernels import default_interpret
+from repro.kernels.lans_kernel import (
+    LANES, SCALARS, TILE_ROWS, _acc_shape, _acc_spec, _fold, _guarded_inv,
+    sum_partials)
 
 
 def _lamb_phase1_kernel(scal_ref, g_ref, m_ref, v_ref, x_ref,
@@ -47,29 +50,34 @@ def _lamb_phase1_kernel(scal_ref, g_ref, m_ref, v_ref, x_ref,
     def _init():
         part_out[...] = jnp.zeros_like(part_out)
 
-    part_out[0, 0] += jnp.sum(u * u)
-    part_out[0, 1] += jnp.sum(x * x)
+    part_out[0] += _fold(u * u)
+    part_out[1] += _fold(x * x)
 
 
 def lamb_phase1(scalars, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
-                interpret: bool = True):
+                interpret=None):
+    """Returns (m_new, v_new, norms): norms is the (1, 8) row
+    [||u||^2, ||x||^2, 0, ...]."""
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = g2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0
     grid = (rows // TILE_ROWS,)
     tile = pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0))
     kern = functools.partial(_lamb_phase1_kernel, beta1=beta1, beta2=beta2, eps=eps)
-    return pl.pallas_call(
+    m_new, v_new, partials = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 8), lambda i: (0, 0)), tile, tile, tile, tile],
-        out_specs=[tile, tile, pl.BlockSpec((1, 8), lambda i: (0, 0))],
+        in_specs=[SCALARS, tile, tile, tile, tile],
+        out_specs=[tile, tile, _acc_spec(2)],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((1, 8), jnp.float32),
+            _acc_shape(2),
         ],
         interpret=interpret,
     )(scalars, g2d, m2d, v2d, x2d)
+    return m_new, v_new, sum_partials(partials)
 
 
 def _lamb_phase2_kernel(scal_ref, norm_ref, m_ref, v_ref, x_ref, x_out,
@@ -99,7 +107,9 @@ def _lamb_phase2_kernel(scal_ref, norm_ref, m_ref, v_ref, x_ref, x_out,
 
 
 def lamb_phase2(scalars, norms, m2d, v2d, x2d, *, beta1, beta2, eps,
-                interpret: bool = True):
+                interpret=None):
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = m2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0
     grid = (rows // TILE_ROWS,)
@@ -108,11 +118,7 @@ def lamb_phase2(scalars, norms, m2d, v2d, x2d, *, beta1, beta2, eps,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            tile, tile, tile,
-        ],
+        in_specs=[SCALARS, SCALARS, tile, tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), x2d.dtype),
         interpret=interpret,

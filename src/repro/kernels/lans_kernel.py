@@ -13,10 +13,13 @@ for VMEM with (8,128)-aligned blocks:
                           direction d (paper eq. 7) and apply x <- x - eta*d
 
 Reductions use the sequential-grid accumulation idiom (output block mapped to
-(0,0) for every grid step, initialised at i==0). All arithmetic is fp32 in
-VREGs regardless of storage dtype; traced scalars (bias corrections, eta,
-flags) ride in a (1, 8) fp32 operand so the kernel needs no retracing across
-steps.
+block 0 for every grid step, initialised at i==0) and stay in vector form: each
+tile folds into a lane-dense (8, 128) fp32 accumulator with elementwise adds,
+and the wrapper sums that block outside the kernel. The TPU compiler refuses
+scalar stores to VMEM, so no kernel writes a reduced scalar. All arithmetic is
+fp32 in VREGs regardless of storage dtype; traced scalars (bias corrections,
+eta, flags, norms) ride in a (1, 8) fp32 SMEM operand so the kernel needs no
+retracing across steps.
 
 Tile size: (256, 128) fp32 = 128 KiB; phase 1 holds 4 input + 2 output tiles
 (~0.75 MiB), far under the ~16 MiB v5e VMEM budget, leaving room for
@@ -29,9 +32,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 TILE_ROWS = 256
 LANES = 128
+SUBLANES = 8
+
+# The (1, 8) fp32 scalar operands live in SMEM whole: read one element at a
+# time, they need no vector load.
+SCALARS = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _guarded_inv(sq: jnp.ndarray, eps_floor: float = 1e-38) -> jnp.ndarray:
@@ -54,6 +65,20 @@ def _guarded_scale(x: jnp.ndarray, sq: jnp.ndarray) -> jnp.ndarray:
 # phase 0: sum-of-squares reduction
 # ---------------------------------------------------------------------------
 
+def _fold(x: jnp.ndarray) -> jnp.ndarray:
+    """(TILE_ROWS, 128) -> (8, 128) partial sums: vreg-wise adds only."""
+    return x.reshape(-1, SUBLANES, LANES).sum(axis=0)
+
+
+def _acc_spec(n: int) -> pl.BlockSpec:
+    """n lane-dense (8, 128) accumulators, revisited by every grid step."""
+    return pl.BlockSpec((n, SUBLANES, LANES), lambda i: (0, 0, 0))
+
+
+def _acc_shape(n: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((n, SUBLANES, LANES), jnp.float32)
+
+
 def _sq_norm_kernel(x_ref, out_ref):
     i = pl.program_id(0)
 
@@ -62,11 +87,13 @@ def _sq_norm_kernel(x_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    out_ref[0, 0] += jnp.sum(x * x)
+    out_ref[0] += _fold(x * x)
 
 
-def sq_norm(x2d: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def sq_norm(x2d: jnp.ndarray, *, interpret=None) -> jnp.ndarray:
     """Sum of squares of a (rows, 128) array, rows % TILE_ROWS == 0."""
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = x2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0, x2d.shape
     grid = (rows // TILE_ROWS,)
@@ -74,11 +101,11 @@ def sq_norm(x2d: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
         _sq_norm_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((TILE_ROWS, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=_acc_spec(1),
+        out_shape=_acc_shape(1),
         interpret=interpret,
     )(x2d)
-    return out[0, 0]
+    return jnp.sum(out)
 
 
 # ---------------------------------------------------------------------------
@@ -114,13 +141,23 @@ def _lans_phase1_kernel(scal_ref, g_ref, m_ref, v_ref, x_ref,
     def _init():
         part_out[...] = jnp.zeros_like(part_out)
 
-    part_out[0, 0] += jnp.sum(r_full * r_full)
-    part_out[0, 1] += jnp.sum(c_full * c_full)
-    part_out[0, 2] += jnp.sum(x * x)
+    part_out[0] += _fold(r_full * r_full)
+    part_out[1] += _fold(c_full * c_full)
+    part_out[2] += _fold(x * x)
+
+
+def sum_partials(partials: jnp.ndarray) -> jnp.ndarray:
+    """(n, 8, 128) accumulators -> the (1, 8) norms row phase 2 reads."""
+    sums = jnp.sum(partials, axis=(1, 2))
+    return jnp.zeros((1, 8), jnp.float32).at[0, :sums.shape[0]].set(sums)
 
 
 def lans_phase1(scalars, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
-                interpret: bool = True):
+                interpret=None):
+    """Returns (m_new, v_new, norms): norms is the (1, 8) row
+    [||r+lam*x||^2, ||c+lam*x||^2, ||x||^2, 0, ...]."""
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = g2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0
     grid = (rows // TILE_ROWS,)
@@ -129,24 +166,21 @@ def lans_phase1(scalars, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
     m_new, v_new, partials = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),  # traced scalars
-            tile, tile, tile, tile,
-        ],
-        out_specs=[tile, tile, pl.BlockSpec((1, 8), lambda i: (0, 0))],
+        in_specs=[SCALARS, tile, tile, tile, tile],
+        out_specs=[tile, tile, _acc_spec(3)],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((1, 8), jnp.float32),
+            _acc_shape(3),
         ],
         interpret=interpret,
     )(scalars, g2d, m2d, v2d, x2d)
-    return m_new, v_new, partials
+    return m_new, v_new, sum_partials(partials)
 
 
 # ---------------------------------------------------------------------------
 # phase 2: apply the update
-# scalars layout: [bc1, bc2, eta, lam, trust_flag, g_sq, r_sq+c_sq+x_sq via norms]
+# scalars layout: [bc1, bc2, eta, lam, trust_flag, g_sq, 0, 0]
 # norms layout:   [r_sq, c_sq, x_sq, 0, 0, 0, 0, 0]
 # ---------------------------------------------------------------------------
 
@@ -205,7 +239,9 @@ def _lans_phase2_cast_kernel(scal_ref, norm_ref, g_ref, m_ref, v_ref, x_ref,
 
 
 def lans_phase2(scalars, norms, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
-                interpret: bool = True):
+                interpret=None):
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = g2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0
     grid = (rows // TILE_ROWS,)
@@ -214,11 +250,7 @@ def lans_phase2(scalars, norms, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            tile, tile, tile, tile,
-        ],
+        in_specs=[SCALARS, SCALARS, tile, tile, tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), x2d.dtype),
         interpret=interpret,
@@ -226,12 +258,14 @@ def lans_phase2(scalars, norms, g2d, m2d, v2d, x2d, *, beta1, beta2, eps,
 
 
 def lans_phase2_cast(scalars, norms, g2d, m2d, v2d, x2d, *, lp_dtype,
-                     beta1, beta2, eps, interpret: bool = True):
+                     beta1, beta2, eps, interpret=None):
     """Phase 2 with fused low-precision cast: returns (x_new_f32, x_new_lp).
 
     TILE_ROWS=256 respects the (16,128) bf16 / fp16 minimum tile, so the
     same grid works for every lp_dtype.
     """
+    if interpret is None:
+        interpret = default_interpret()
     rows, lanes = g2d.shape
     assert lanes == LANES and rows % TILE_ROWS == 0
     grid = (rows // TILE_ROWS,)
@@ -241,11 +275,7 @@ def lans_phase2_cast(scalars, norms, g2d, m2d, v2d, x2d, *, lp_dtype,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
-            tile, tile, tile, tile,
-        ],
+        in_specs=[SCALARS, SCALARS, tile, tile, tile, tile],
         out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),

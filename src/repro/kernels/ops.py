@@ -6,8 +6,8 @@ as (rows, 128). Zero padding is exact for every phase (padded lanes carry
 g = m = v = x = 0, contributing nothing to any norm and receiving a zero
 update), so no masking pass is needed.
 
-`interpret` defaults to True: this container is CPU-only; on real TPU call
-with interpret=False.
+`interpret=None` resolves through `repro.kernels.default_interpret()`:
+compiled on a TPU, interpreted on any other backend.
 """
 from __future__ import annotations
 
@@ -45,7 +45,7 @@ def _from_tiles(t2d: jnp.ndarray, n: int, shape, dtype) -> jnp.ndarray:
 def fused_lans_step(
     g, m, v, x, *, eta, step,
     beta1: float = 0.9, beta2: float = 0.999, eps: float = 1e-6,
-    lam: float = 0.01, apply_trust: bool = True, interpret: bool = True,
+    lam: float = 0.01, apply_trust: bool = True, interpret=None,
 ) -> StepOut:
     """One fused LANS update for a single parameter tensor (any shape/dtype).
 
@@ -103,7 +103,7 @@ class MixedStepOut(NamedTuple):
 def fused_lans_mixed_step(
     g, m, v, x, *, eta, step, lp_dtype,
     beta1: float = 0.9, beta2: float = 0.999, eps: float = 1e-6,
-    lam: float = 0.01, apply_trust: bool = True, interpret: bool = True,
+    lam: float = 0.01, apply_trust: bool = True, interpret=None,
 ) -> MixedStepOut:
     """Fused LANS step on fp32 master `x` that ALSO emits the lp_dtype model
     copy from the same phase-2 pass — the cast-and-apply path mixed-precision
@@ -151,7 +151,7 @@ def fused_lans_mixed_step(
 def fused_lamb_step(
     g, m, v, x, *, eta, step, clip=1.0,
     beta1: float = 0.9, beta2: float = 0.999, eps: float = 1e-6,
-    lam: float = 0.01, apply_trust: bool = True, interpret: bool = True,
+    lam: float = 0.01, apply_trust: bool = True, interpret=None,
 ) -> StepOut:
     """One fused LAMB update; ``clip`` is the caller-computed global-clip factor."""
     g2d, n = _to_tiles(g)
@@ -186,7 +186,7 @@ def fused_lamb_step(
     )
 
 
-def block_sq_norm(x, *, interpret: bool = True) -> jnp.ndarray:
+def block_sq_norm(x, *, interpret=None) -> jnp.ndarray:
     """Kernel-backed sum-of-squares for arbitrary-shape tensors."""
     x2d, _ = _to_tiles(x)
     return lans_kernel.sq_norm(x2d, interpret=interpret)

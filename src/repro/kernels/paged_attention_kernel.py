@@ -105,9 +105,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import NEG_INF
+from repro.kernels import NEG_INF, default_interpret
 
 _VALID_FLOOR = -1e37     # any real logit is far above this
+# fp32 contractions on the MXU: without it the TPU may round the fp32
+# softmax weights to bf16 before the PV product.
+_FP32 = jax.lax.Precision.HIGHEST
 
 # TPU register/VMEM tiling: the last ("lane") dim tiles by 128 always;
 # the second-to-last ("sublane") dim tiles by 8 for 4-byte dtypes and 16
@@ -118,11 +121,6 @@ VMEM_BUDGET = int(16 * 1024 * 1024 * 0.9)
 
 _TUNED_TABLE = pathlib.Path(__file__).resolve().parent.parent / \
     "configs" / "paged_attn_tuned.json"
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode unless running on real TPU."""
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -157,10 +155,36 @@ def tile_alignment_problems(block_size: int, head_dim: int, dtype) -> list:
     return problems
 
 
+def block_rule_problems(name: str, block: tuple, array: tuple) -> list:
+    """The TPU compiler's rule for one BlockSpec: each of the block's last
+    two dims is a multiple of its tile (8 sublanes, 128 lanes) or equals
+    the array's whole dim. `array` may be given by its trailing dims."""
+    problems = []
+    for dim, tile, what in ((-2, 8, "sublane"), (-1, TILE_LANE, "lane")):
+        if block[dim] != array[dim] and block[dim] % tile:
+            problems.append(
+                f"{name} block {block} over {array}: {what} dim "
+                f"{block[dim]} is neither a multiple of {tile} nor the "
+                f"array's {array[dim]}")
+    return problems
+
+
+def fused_blocks(S: int, h: int, n_kv: int, hd: int, bs: int) -> dict:
+    """Block shape of each blocked operand of `paged_attention_fused` and
+    the trailing dims of the array it tiles (leading dims count slots or
+    arena blocks; the block rule ignores them). The outputs reuse the q,
+    arena and pos blocks."""
+    return {"q": ((1, S, h, hd), (S, h, hd)),
+            "new_kv": ((1, S, n_kv, hd), (S, n_kv, hd)),
+            "kv_arena": ((1, bs, n_kv, hd), (bs, n_kv, hd)),
+            "pos": ((1, 1, bs), (1, bs))}
+
+
 def kernel_fit_problems(block_size: int, head_dim: int, n_heads: int,
                         n_kv: int, dtype, *, S: int = 1,
                         vmem_budget: int = VMEM_BUDGET) -> list:
-    """Tile-alignment plus VMEM-scratch sizing for one kernel launch.
+    """Tile-alignment, the compiler's block rule and VMEM-scratch sizing
+    for one kernel launch.
 
     The VMEM estimate covers the fused kernel at production head counts:
     fp32 online-softmax scratch (m, l, acc), the double-buffered K/V/pos
@@ -168,6 +192,9 @@ def kernel_fit_problems(block_size: int, head_dim: int, n_heads: int,
     / attention-out blocks.
     """
     problems = tile_alignment_problems(block_size, head_dim, dtype)
+    for name, (block, array) in fused_blocks(
+            S, n_heads, n_kv, head_dim, block_size).items():
+        problems += block_rule_problems(name, block, array)
     isz = jnp.dtype(dtype).itemsize
     blk = block_size * n_kv * head_dim * isz + block_size * 4  # K|V + pos
     scratch = 4 * S * n_heads * (2 + head_dim)                 # m, l, acc fp32
@@ -232,13 +259,23 @@ def tuned_grid_order(backend: str, head_dim: int, n_kv: int,
 
 def _compiler_params(grid_order: str):
     if grid_order == "parallel":
-        return pltpu.TPUCompilerParams(
+        return pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     if grid_order != "arbitrary":
         raise ValueError(
             f"grid_order must be 'arbitrary' or 'parallel', got {grid_order}")
-    return pltpu.TPUCompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
+
+
+def pos_rows(pos_arena):
+    """(n_blocks, bs) positions as (n_blocks, 1, bs): one arena block's
+    row is then a (1, 1, bs) block whose last two dims equal the array's,
+    which the TPU compiler accepts for any block_size (a (1, bs) block of
+    the 2-D arena is refused: its sublane dim is neither 8-aligned nor
+    the whole array). The kernels read and write positions in this form."""
+    n_blocks, bs = pos_arena.shape
+    return pos_arena.reshape(n_blocks, 1, bs)
 
 
 # --------------------------------------------------------------------------
@@ -250,7 +287,7 @@ def _online_fold(q, k, v, kp, qp, m_ref, l_ref, acc_ref, *,
     """Fold one key block into the (m, l, acc) scratch state.
 
     q (S, h, hd) fp32; k/v (T, n_kv, hd) any float (upcast here);
-    kp (1, T) int32 key positions (-1 = invalid row); qp (S,) int32.
+    kp (1, T) int32 key positions (-1 = invalid row); qp (S, 1) int32.
     """
     S, h, hd = q.shape
     g = h // n_kv
@@ -264,6 +301,7 @@ def _online_fold(q, k, v, kp, qp, m_ref, l_ref, acc_ref, *,
         q.reshape(S, n_kv, g, hd).swapaxes(0, 1).reshape(n_kv, S * g, hd),
         k,
         dimension_numbers=(((2,), (2,)), ((0,), (1,))),
+        precision=_FP32,
         preferred_element_type=jnp.float32,    # (n_kv, S*g, T)
     ).reshape(n_kv, S, g, -1).swapaxes(0, 1).reshape(S, h, -1) * scale
     if softcap is not None:
@@ -271,9 +309,9 @@ def _online_fold(q, k, v, kp, qp, m_ref, l_ref, acc_ref, *,
 
     ok = jnp.broadcast_to(kp >= 0, (S, kp.shape[1]))
     if causal:                                 # row s masks against ITS pos
-        ok = ok & (kp <= qp[:, None])
+        ok = ok & (kp <= qp)
     if window is not None:
-        ok = ok & ((qp[:, None] - kp) < window)
+        ok = ok & ((qp - kp) < window)
     logits = jnp.where(ok[:, None, :], logits, NEG_INF)
 
     m_prev = m_ref[...].reshape(S, h)
@@ -289,6 +327,7 @@ def _online_fold(q, k, v, kp, qp, m_ref, l_ref, acc_ref, *,
         e.reshape(S, n_kv, g, -1).swapaxes(0, 1).reshape(n_kv, S * g, -1),
         v,
         dimension_numbers=(((2,), (0,)), ((0,), (1,))),
+        precision=_FP32,
         preferred_element_type=jnp.float32,    # (n_kv, S*g, hd)
     ).reshape(n_kv, S, g, hd).swapaxes(0, 1).reshape(S, h, hd)
 
@@ -297,6 +336,18 @@ def _online_fold(q, k, v, kp, qp, m_ref, l_ref, acc_ref, *,
                   + jnp.sum(e, axis=2)).reshape(S * h, 1)
     acc_ref[...] = (alpha.reshape(S * h, 1) * acc_ref[...]
                     + pv.reshape(S * h, hd))
+
+
+def _slot_positions(qpos_ref, b, S: int, axis: int):
+    """Slot b's S query positions as an (S, 1) (axis 0) or (1, S) (axis 1)
+    int32 vector. SMEM holds the prefetched positions and only yields one
+    scalar per load, so the vector is assembled from S scalar loads."""
+    shape = (S, 1) if axis == 0 else (1, S)
+    idx = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    out = jnp.zeros(shape, jnp.int32)
+    for s in range(S):                         # S static and small
+        out = jnp.where(idx == s, qpos_ref[b, s], out)
+    return out
 
 
 def _finish_out(out_ref, m_ref, l_ref, acc_ref, S, h, hd):
@@ -325,8 +376,8 @@ def _paged_attn_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
 
     q = q_ref[0].astype(jnp.float32)           # (S, h, hd)
     S, h, hd = q.shape
-    qp = qpos_ref[b]                           # (S,) this slot's positions
-    _online_fold(q, k_ref[0], v_ref[0], pos_ref[...], qp,
+    qp = _slot_positions(qpos_ref, b, S, 0)    # (S, 1) this slot's positions
+    _online_fold(q, k_ref[0], v_ref[0], pos_ref[0], qp,
                  m_ref, l_ref, acc_ref, scale=scale, causal=causal,
                  window=window, softcap=softcap, n_kv=n_kv)
 
@@ -394,7 +445,7 @@ def paged_attention(q, k_arena, v_arena, pos_arena, tables, q_pos, *,
                          lambda b, j, tbl, qp: (tbl[b, j], 0, 0, 0)),
             pl.BlockSpec((1, bs, n_kv, hd),
                          lambda b, j, tbl, qp: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs), lambda b, j, tbl, qp: (tbl[b, j], 0)),
+            pl.BlockSpec((1, 1, bs), lambda b, j, tbl, qp: (tbl[b, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, S, h, hd),
                                lambda b, j, tbl, qp: (b, 0, 0, 0)),
@@ -414,7 +465,7 @@ def paged_attention(q, k_arena, v_arena, pos_arena, tables, q_pos, *,
         compiler_params=_compiler_params(grid_order),
         interpret=interpret,
     )(tables.astype(jnp.int32), q_pos.astype(jnp.int32),
-      q, k_arena, v_arena, pos_arena)
+      q, k_arena, v_arena, pos_rows(pos_arena))
     return out[:, 0] if squeeze else out
 
 
@@ -465,7 +516,7 @@ def _paged_attn_fused_kernel(tbl_ref, qpos_ref, cur_ref, w_ref,
 
     q = q_ref[0].astype(jnp.float32)           # (S, h, hd)
     S, h, hd = q.shape
-    qp = qpos_ref[b]                           # (S,) this slot's positions
+    qp = _slot_positions(qpos_ref, b, S, 0)    # (S, 1) this slot's positions
 
     # The S new rows fold ONCE as a virtual key block (positions q_pos):
     # the streamed destination blocks still hold pre-scatter bytes at the
@@ -474,11 +525,12 @@ def _paged_attn_fused_kernel(tbl_ref, qpos_ref, cur_ref, w_ref,
     # row_margin contract for wrapped ring rows (module docstring).
     @pl.when(j == 0)
     def _fold_new_rows():
-        _online_fold(q, kn_ref[0], vn_ref[0], qp.reshape(1, S), qp,
+        _online_fold(q, kn_ref[0], vn_ref[0],
+                     _slot_positions(qpos_ref, b, S, 1), qp,
                      m_ref, l_ref, acc_ref, scale=scale, causal=causal,
                      window=window, softcap=softcap, n_kv=n_kv)
 
-    _online_fold(q, k_ref[0], v_ref[0], pos_ref[...], qp,
+    _online_fold(q, k_ref[0], v_ref[0], pos_ref[0], qp,
                  m_ref, l_ref, acc_ref, scale=scale, causal=causal,
                  window=window, softcap=softcap, n_kv=n_kv)
 
@@ -503,7 +555,7 @@ def _paged_attn_fused_kernel(tbl_ref, qpos_ref, cur_ref, w_ref,
     def _write_epilogue():
         kbuf = k_ref[0]                        # (bs, n_kv, hd) arena dtype
         vbuf = v_ref[0]
-        pbuf = pos_ref[...]                    # (1, bs) int32
+        pbuf = pos_ref[0]                      # (1, bs) int32
         rows3 = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
         rows2 = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         for hit, off, s in hits:               # S static and small: unrolled
@@ -513,7 +565,7 @@ def _paged_attn_fused_kernel(tbl_ref, qpos_ref, cur_ref, w_ref,
             pbuf = jnp.where(hit & (rows2 == off), qpos_ref[b, s], pbuf)
         ko_ref[0] = kbuf
         vo_ref[0] = vbuf
-        po_ref[...] = pbuf
+        po_ref[0] = pbuf
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -566,33 +618,36 @@ def paged_attention_fused(q, k_new, v_new, k_arena, v_arena, pos_arena,
     q_pos = q_pos.astype(jnp.int32)
     cursor = cursor.astype(jnp.int32)
     wmap = _flush_map(tables, q_pos, cursor, bs, nb)
+    pos3 = pos_rows(pos_arena)
+    blk = {k: shapes[0] for k, shapes in
+           fused_blocks(S, h, n_kv, hd, bs).items()}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                 # tables, q_pos, cursor, W
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, S, h, hd),
+            pl.BlockSpec(blk["q"],
                          lambda b, j, tbl, qp, cur, w: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, n_kv, hd),
+            pl.BlockSpec(blk["new_kv"],
                          lambda b, j, tbl, qp, cur, w: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, n_kv, hd),
+            pl.BlockSpec(blk["new_kv"],
                          lambda b, j, tbl, qp, cur, w: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, n_kv, hd),
+            pl.BlockSpec(blk["kv_arena"],
                          lambda b, j, tbl, qp, cur, w: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, n_kv, hd),
+            pl.BlockSpec(blk["kv_arena"],
                          lambda b, j, tbl, qp, cur, w: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs),
-                         lambda b, j, tbl, qp, cur, w: (tbl[b, j], 0)),
+            pl.BlockSpec(blk["pos"],
+                         lambda b, j, tbl, qp, cur, w: (tbl[b, j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, S, h, hd),
+            pl.BlockSpec(blk["q"],
                          lambda b, j, tbl, qp, cur, w: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, n_kv, hd),
+            pl.BlockSpec(blk["kv_arena"],
                          lambda b, j, tbl, qp, cur, w: (w[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, n_kv, hd),
+            pl.BlockSpec(blk["kv_arena"],
                          lambda b, j, tbl, qp, cur, w: (w[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs),
-                         lambda b, j, tbl, qp, cur, w: (w[b, j], 0)),
+            pl.BlockSpec(blk["pos"],
+                         lambda b, j, tbl, qp, cur, w: (w[b, j], 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((S * h, 1), jnp.float32),   # running max m
@@ -610,7 +665,7 @@ def paged_attention_fused(q, k_new, v_new, k_arena, v_arena, pos_arena,
             jax.ShapeDtypeStruct((B, S, h, hd), jnp.float32),
             jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
             jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
-            jax.ShapeDtypeStruct(pos_arena.shape, pos_arena.dtype),
+            jax.ShapeDtypeStruct(pos3.shape, pos3.dtype),
         ],
         # Flattened-input indices INCLUDE the 4 scalar-prefetch operands:
         # inputs are [tbl, qp, cur, W, q, k_new, v_new, k, v, pos] so the
@@ -619,5 +674,6 @@ def paged_attention_fused(q, k_new, v_new, k_arena, v_arena, pos_arena,
         compiler_params=_compiler_params(grid_order),
         interpret=interpret,
     )(tables, q_pos, cursor, wmap, q, k_new, v_new,
-      k_arena, v_arena, pos_arena)
-    return (out[:, 0] if squeeze else out), k_out, v_out, pos_out
+      k_arena, v_arena, pos3)
+    return ((out[:, 0] if squeeze else out), k_out, v_out,
+            pos_out.reshape(pos_arena.shape))

@@ -28,7 +28,11 @@ from repro.core.optim import lans
 from repro.core.schedules import warmup_hold_decay
 from repro.distributed import sharding as shd
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import V5E, make_production_mesh
+
+# The placeholder devices are host CPUs; the roofline is for the chip the
+# production mesh is made of.
+TARGET_KIND = V5E
 
 
 def _shardings(mesh, spec_tree):
@@ -63,6 +67,7 @@ def lower_one(arch_name: str, shape_name: str, multi_pod: bool,
         "arch": arch_name, "shape": shape_name,
         "mesh": "pod2" if multi_pod else "pod1", "n_chips": n_chips,
         "kind": shape.kind, "params": arch.param_count(),
+        "device_kind": TARGET_KIND,
         "zero3": arch.zero3,
     }
     if not arch.supports(shape_name):
@@ -208,7 +213,8 @@ def lower_one(arch_name: str, shape_name: str, multi_pod: bool,
             lowered = jitted.lower(params_abs, batch_abs, cache_abs)
             compiled = lowered.compile()
 
-    analysis = hlo_analysis.analyze_compiled(lowered, compiled, n_chips)
+    analysis = hlo_analysis.analyze_compiled(lowered, compiled, n_chips,
+                                             device_kind=TARGET_KIND)
 
     # useful-FLOPs ratio: MODEL_FLOPS / HLO_FLOPs (catches remat/redundancy)
     n_active = active_params(arch)

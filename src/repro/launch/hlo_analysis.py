@@ -6,11 +6,12 @@ sum the operand bytes of every collective op:
 
   all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 
-Roofline terms per §Roofline (v5e constants from launch/mesh.py):
+Roofline terms per §Roofline, with the target chip's peaks from
+launch/mesh.PEAKS (v5e: 197e12 FLOP/s, 819e9 B/s HBM, 50e9 B/s per link):
 
-  compute   = HLO_FLOPs / (chips * 197e12)
-  memory    = HLO_bytes / (chips * 819e9)
-  collective= collective_bytes / (chips * 50e9)
+  compute   = HLO_FLOPs / (chips * flops_bf16)
+  memory    = HLO_bytes / (chips * hbm_bw)
+  collective= collective_bytes / (chips * ici_bw)
 """
 from __future__ import annotations
 
@@ -105,15 +106,18 @@ def op_counts(hlo_text: str, kinds=("scatter",)) -> Dict[str, int]:
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   coll_bytes_total: float, n_chips: int) -> Dict[str, float]:
-    """Roofline seconds. Inputs are GLOBAL totals; divide by chip count.
+                   coll_bytes_total: float, n_chips: int,
+                   device_kind: str) -> Dict[str, float]:
+    """Roofline seconds on `device_kind` chips. Inputs are GLOBAL totals;
+    divide by chip count.
 
     NB: when costs come from the partitioned (per-chip) HLO program, pass
     n_chips=1 — the program is already one chip's share.
     """
-    compute_s = flops / (n_chips * mesh_lib.PEAK_FLOPS_BF16)
-    memory_s = bytes_accessed / (n_chips * mesh_lib.HBM_BW)
-    collective_s = coll_bytes_total / (n_chips * mesh_lib.ICI_BW)
+    peaks = mesh_lib.chip_peaks(device_kind)
+    compute_s = flops / (n_chips * peaks.flops_bf16)
+    memory_s = bytes_accessed / (n_chips * peaks.hbm_bw)
+    collective_s = coll_bytes_total / (n_chips * peaks.ici_bw)
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=terms.get)
@@ -131,7 +135,8 @@ def model_flops_inference(n_params_active: int, n_tokens: int) -> float:
     return 2.0 * n_params_active * n_tokens
 
 
-def analyze_compiled(lowered, compiled, n_chips: int) -> dict:
+def analyze_compiled(lowered, compiled, n_chips: int,
+                     device_kind: str) -> dict:
     from repro.launch import hlo_cost
 
     cost = compiled.cost_analysis()
@@ -166,7 +171,8 @@ def analyze_compiled(lowered, compiled, n_chips: int) -> dict:
         mem["error"] = str(e)
 
     # The SPMD-partitioned HLO is the per-chip program: costs are per chip.
-    terms = roofline_terms(flops, byts, coll_total, n_chips=1)
+    terms = roofline_terms(flops, byts, coll_total, n_chips=1,
+                           device_kind=device_kind)
     return {
         "flops": flops,                      # per chip
         "flops_global": flops * n_chips,

@@ -11,19 +11,17 @@ dryrun.py forces 512 host devices).
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
+from jax.sharding import Mesh
 
 
-def _mk(shape, axes):
-    # AxisType (and make_mesh's axis_types kwarg) only exist on newer jax;
-    # older versions treat every axis as Auto already, so plain make_mesh is
-    # semantically identical there.
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+def _mk(shape, axes, devices=None):
+    # make_mesh orders the devices for the physical topology; re-wrapping
+    # them in Mesh keeps every axis Auto (make_mesh defaults to Explicit),
+    # so shardings propagate through GSPMD as the step builders expect.
+    return Mesh(jax.make_mesh(shape, axes, devices=devices).devices, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,13 +30,36 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mk(shape, axes)
 
 
-def make_local_mesh(*, data: int = 1, model: int = 1):
-    """Mesh over whatever devices exist locally (tests / CPU examples)."""
-    return _mk((data, model), ("data", "model"))
+def make_local_mesh(*, data: int = 1, model: int = 1, devices=None):
+    """Mesh over the first data*model local devices (or over `devices`)."""
+    return _mk((data, model), ("data", "model"), devices)
 
 
-# TPU v5e hardware constants for the roofline model (per chip).
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW = 50e9                  # bytes/s per link (~ per-chip usable)
-HBM_BYTES = 16 * 1024**3       # 16 GiB
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks for the roofline model."""
+
+    flops_bf16: float          # FLOP/s
+    hbm_bw: float              # bytes/s
+    ici_bw: float              # bytes/s per link
+    hbm_bytes: int
+
+
+# Keyed by jax's `device_kind`. Source: Google Cloud documentation, "TPU
+# v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links (50 GB/s each).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   hbm_bytes=16 * 1024**3),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of `device_kind`; a kind with no published entry
+    raises rather than borrowing another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None

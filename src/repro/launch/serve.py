@@ -270,6 +270,8 @@ def build_parser():
 
 def main():
     args = build_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     errs = flag_errors(args)
     if errs:

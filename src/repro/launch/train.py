@@ -1,9 +1,10 @@
 """Training launcher — runs real steps on local devices.
 
-On this CPU container it trains the REDUCED configs (or bert-large at a
-small size) end-to-end with the paper's full recipe: LANS + warmup-hold-
-decay schedule + sharded-without-replacement data. On TPU the same entry
-point scales to the production mesh (--mesh production).
+Trains with the paper's full recipe: LANS + warmup-hold-decay schedule +
+sharded-without-replacement data. --reduced (the default) picks the small
+CPU-sized config of --arch; --full the published widths. The step runs
+data-parallel over every local device (batch split over the `data` axis;
+--batch must divide evenly): one CPU, one TPU chip, or all chips of a host.
 
   PYTHONPATH=src python -m repro.launch.train --arch bert-large --steps 50 \
       --batch 32 --seq 128 --optimizer lans
@@ -22,7 +23,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import time
 
 import jax
@@ -79,7 +79,7 @@ def make_data(arch, *, batch: int, seq: int, num_workers: int = 1, seed: int = 0
     return lm_batch_iterator(corpus, spec, per_worker_batch=batch, seq_len=seq)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bert-large")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -102,8 +102,34 @@ def main():
     ap.add_argument("--metrics", default="", help="JSONL metrics path")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+@dataclasses.dataclass
+class Trainer:
+    """Everything one training run holds: built by `make_trainer`,
+    advanced by `train_steps`."""
+
+    args: argparse.Namespace
+    arch: object
+    sched: object
+    policy: object
+    mesh: object
+    step_fn: object
+    pspec: object
+    ospec: object
+    params: object
+    opt_state: object
+    data: object
+    compiled: object = None     # the AOT-compiled step, set by train_steps
+    compile_s: float = 0.0
+
+
+def make_trainer(args, devices=None) -> Trainer:
+    """Config, optimizer, data-parallel mesh, initial params and state.
+
+    The mesh is (data=len(devices), model=1) over `devices`, all local
+    devices by default."""
     arch = reduced_arch(args.arch) if args.reduced else get_arch(args.arch)
     if args.reduced:
         args.seq = min(args.seq, arch.cfg.max_pos if arch.kind == "bert"
@@ -124,48 +150,81 @@ def main():
 
     # One step builder for every entry point: build_train_step owns the
     # mixed-precision wiring (master-weight wrapper, loss scaling, metrics).
-    from repro.distributed.steps import build_train_step, jit_train_step
+    from repro.distributed.steps import build_train_step
     from repro.launch.mesh import make_local_mesh
 
-    mesh = make_local_mesh(data=1, model=1)
+    devices = list(devices) if devices is not None else jax.devices()
+    if args.batch % len(devices):
+        raise ValueError(f"--batch {args.batch} does not split evenly over "
+                         f"{len(devices)} devices")
+    mesh = make_local_mesh(data=len(devices), model=1, devices=devices)
     step_fn, init_fn, specs_for = build_train_step(
         arch.loss_fn, tx, mesh, param_init_fn=arch.init, policy=policy)
     params, opt_state = init_fn(jax.random.PRNGKey(args.seed))
     pspec, ospec = specs_for(params, opt_state)
-
-    from repro.metrics import MetricsLogger
-
     data = make_data(arch, batch=args.batch, seq=args.seq, seed=args.seed)
-    t0 = time.time()
-    losses = []
-    logger = MetricsLogger(args.metrics or None)
-    step = None
-    with mesh:
-        for i in range(args.steps):
-            batch = {k: jnp.asarray(v) for k, v in next(data).items()}
-            if step is None:
-                step = jit_train_step(step_fn, mesh, pspec, ospec, batch)
-            params, opt_state, metrics = step(params, opt_state, batch)
+    return Trainer(args, arch, sched, policy, mesh, step_fn, pspec, ospec,
+                   params, opt_state, data)
+
+
+def train_steps(tr: Trainer, steps: int, logger=None) -> dict:
+    """Run `steps` steps. The first call compiles the step ahead of time
+    (its seconds land in tr.compile_s); every step's wall time ends when
+    its params, state and metrics are ready on the device.
+
+    Returns {"losses": [...], "step_s": [...]}."""
+    from repro.distributed.steps import jit_train_step
+
+    args, policy = tr.args, tr.policy
+    losses, step_s = [], []
+    with tr.mesh:
+        for i in range(steps):
+            batch = {k: jnp.asarray(v) for k, v in next(tr.data).items()}
+            if tr.compiled is None:
+                t0 = time.perf_counter()
+                tr.compiled = jit_train_step(
+                    tr.step_fn, tr.mesh, tr.pspec, tr.ospec, batch).lower(
+                        tr.params, tr.opt_state, batch).compile()
+                tr.compile_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            tr.params, tr.opt_state, metrics = jax.block_until_ready(
+                tr.compiled(tr.params, tr.opt_state, batch))
+            step_s.append(time.perf_counter() - t0)
             losses.append(float(metrics["loss"]))
+            if logger is None:
+                continue
             extra = {}
             if policy.wants_wrapper:
                 extra = {"loss_scale": metrics["loss_scale"],
                          "overflow_count": metrics["overflow_count"]}
             logger.log(i + 1, loss=metrics["loss"],
-                       lr=sched(jnp.asarray(i)), **extra)
+                       lr=tr.sched(jnp.asarray(i)), **extra)
             if (i + 1) % args.log_every == 0 or i == 0:
                 ls_txt = (f"  scale {float(extra['loss_scale']):.0f}"
                           f"  ovf {int(extra['overflow_count'])}"
                           if extra else "")
                 print(f"step {i+1:5d}  loss {losses[-1]:.4f}  "
                       f"(ema {logger.smoothed_loss:.4f})  "
-                      f"lr {float(sched(jnp.asarray(i))):.2e}  "
-                      f"{(time.time()-t0)/(i+1):.2f}s/step{ls_txt}",
+                      f"lr {float(tr.sched(jnp.asarray(i))):.2e}  "
+                      f"{sum(step_s) / len(step_s):.2f}s/step{ls_txt}",
                       flush=True)
+    return {"losses": losses, "step_s": step_s}
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    tr = make_trainer(args)
+    from repro.metrics import MetricsLogger
+
+    logger = MetricsLogger(args.metrics or None)
+    losses = train_steps(tr, args.steps, logger)["losses"]
     logger.close()
 
     if args.ckpt_dir:
-        ckpt_save(args.ckpt_dir, args.steps, params,
+        ckpt_save(args.ckpt_dir, args.steps, tr.params,
                   metadata={"arch": args.arch, "optimizer": args.optimizer,
                             "final_loss": losses[-1]})
         print("checkpoint saved to", args.ckpt_dir)

@@ -49,7 +49,7 @@ def fused_mixed_lans(
     eps: float = 1e-6,
     weight_decay: float = 0.01,
     decay_mask: Optional[Callable[[str], bool]] = None,
-    interpret: bool = True,
+    interpret=None,
 ) -> GradientTransformation:
     """Kernel-fused LANS + master weights + loss scaling in one transform."""
     ls = loss_scale if loss_scale is not None else policy.make_loss_scale()

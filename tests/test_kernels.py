@@ -363,6 +363,24 @@ def test_tile_alignment_problems():
     assert tile_alignment_problems(8, 128, jnp.bfloat16) != []
 
 
+def test_block_rule_refuses_what_the_compiler_refuses():
+    """The TPU compiler's BlockSpec rule: a (1, bs) row of a 2-D
+    (n_blocks, bs) position arena is refused (sublane dim 1 is neither
+    8-aligned nor the whole array), the (1, 1, bs) row of the
+    (n_blocks, 1, bs) view is accepted, and every block of the fused
+    kernel passes at qwen2.5-14b decode widths."""
+    assert pak.block_rule_problems("pos", (1, 16), (512, 16))
+    assert pak.block_rule_problems("x", (8, 64), (64, 256))   # lane dim
+    assert pak.block_rule_problems("pos", (1, 1, 16), (512, 1, 16)) == []
+    for S in (1, 4):
+        for name, (block, array) in pak.fused_blocks(
+                S, 40, 8, 128, 16).items():
+            assert pak.block_rule_problems(name, block, array) == [], name
+        assert pak.kernel_fit_problems(16, 128, 40, 8, jnp.bfloat16,
+                                       S=S) == []
+    assert pak.pos_rows(jnp.zeros((5, 16), jnp.int32)).shape == (5, 1, 16)
+
+
 def test_ensure_kernel_fit_gates_compiled_only():
     """Problems raise only when the kernel would COMPILE (interpret
     False); the interpret escape hatch downgrades them to advisory."""

@@ -57,6 +57,7 @@ EXPECTED_MODULE_MARKERS = {
     "test_sharding_rules.py": set(),
     "test_speculative.py": {"serving", "spec"},
     "test_system.py": set(),
+    "test_tpu_compile.py": {"kernels"},
 }
 
 _MARK_RE = re.compile(r"pytest\.mark\.([A-Za-z_][A-Za-z0-9_]*)")

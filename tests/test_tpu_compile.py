@@ -1,0 +1,127 @@
+"""AOT compiles of the Pallas kernels for a described TPU v5e.
+
+Interpret mode executes any layout; the TPU compiler does not. These
+tests hand the real compiler the kernels at published widths, against a
+`v5e:2x2` topology that is described, not attached, so a block shape or a
+store the chip would refuse fails here, with no chip. Nothing runs.
+
+The topology is described inside a module fixture: only the worker that
+runs this file loads the TPU library. Keep every test that needs it in
+this one file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import lamb_kernel, lans_kernel, ops
+from repro.kernels.paged_attention_kernel import paged_attention_fused
+
+pytestmark = pytest.mark.kernels
+
+# bert-large FFN weight (1024 x 4096) as the optimizer kernels see it
+BERT_FFN = (1024, 4096)
+ROWS = BERT_FFN[0] * BERT_FFN[1] // lans_kernel.LANES
+# qwen2.5-14b decode: 40 query heads over 8 K/V heads of 128, bf16 arenas
+PAGED = dict(B=8, h=40, n_kv=8, hd=128, bs=16, nb=32, n_blocks=512)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+TILE = (ROWS, lans_kernel.LANES)
+SCAL = (1, 8)
+HP = dict(beta1=0.9, beta2=0.999, eps=1e-6, interpret=False)
+
+# (kernel, operand shapes): every phase of the fused LANS and LAMB steps
+PHASES = {
+    "lans_phase0_sq_norm": (
+        functools.partial(lans_kernel.sq_norm, interpret=False),
+        [(TILE, F32)]),
+    "lans_phase1": (
+        functools.partial(lans_kernel.lans_phase1, **HP),
+        [(SCAL, F32)] + [(TILE, F32)] * 4),
+    "lans_phase2": (
+        functools.partial(lans_kernel.lans_phase2, **HP),
+        [(SCAL, F32), (SCAL, F32)] + [(TILE, F32)] * 4),
+    "lans_phase2_cast_bf16": (
+        functools.partial(lans_kernel.lans_phase2_cast, lp_dtype=BF16, **HP),
+        [(SCAL, F32), (SCAL, F32)] + [(TILE, F32)] * 4),
+    "lamb_phase1": (
+        functools.partial(lamb_kernel.lamb_phase1, **HP),
+        [(SCAL, F32)] + [(TILE, F32)] * 4),
+    "lamb_phase2": (
+        functools.partial(lamb_kernel.lamb_phase2, **HP),
+        [(SCAL, F32), (SCAL, F32)] + [(TILE, F32)] * 3),
+}
+
+
+@pytest.mark.parametrize("phase", sorted(PHASES))
+def test_optimizer_kernel_compiles(one_chip, no_cache, phase):
+    fn, shapes = PHASES[phase]
+    _compile(fn, *shapes, sharding=one_chip)
+
+
+@pytest.mark.parametrize("mixed", [False, True])
+def test_fused_lans_step_compiles(one_chip, no_cache, mixed):
+    """The whole per-tensor step the fused optimizers call: padding,
+    three kernels and the scalar plumbing between them."""
+    def step(g, m, v, x, eta, t):
+        if mixed:
+            return ops.fused_lans_mixed_step(g, m, v, x, eta=eta, step=t,
+                                             lp_dtype=BF16, interpret=False)
+        return ops.fused_lans_step(g, m, v, x, eta=eta, step=t,
+                                   interpret=False)
+    _compile(step, *[(BERT_FFN, F32)] * 4, ((), F32), ((), I32),
+             sharding=one_chip)
+
+
+@pytest.mark.parametrize("S", [1, 4])
+def test_paged_attention_fused_compiles(one_chip, no_cache, S):
+    c = PAGED
+    B, h, kv, hd, bs = c["B"], c["h"], c["n_kv"], c["hd"], c["bs"]
+    arena = (c["n_blocks"], bs, kv, hd)
+
+    def step(q, k_new, v_new, ka, va, pos, tbl, qpos, cur):
+        return paged_attention_fused(q, k_new, v_new, ka, va, pos, tbl, qpos,
+                                     cur, scale=hd ** -0.5, interpret=False)
+    compiled = _compile(
+        step, ((B, S, h, hd), BF16), ((B, S, kv, hd), BF16),
+        ((B, S, kv, hd), BF16), (arena, BF16), (arena, BF16),
+        ((c["n_blocks"], bs), I32), ((B, c["nb"]), I32), ((B, S), I32),
+        ((B,), I32), sharding=one_chip)
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
