@@ -1,0 +1,1 @@
+"""Plain float32 references that decide `correct`; they import nothing of the program."""
