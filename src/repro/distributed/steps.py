@@ -106,18 +106,22 @@ def build_train_step(
                 lambda a: jnp.mean(a, axis=0)
                 if jnp.issubdtype(a.dtype, jnp.inexact) else a[-1], auxs)
 
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
+        # Named scopes reach the compiled HLO's op_name metadata, so a
+        # device profile can be split by them (bench/scopes.py).
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, params)
+            new_params = apply_updates(params, updates)
 
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                             for g in jax.tree.leaves(grads)))
-        metrics = {"loss": loss, "grad_norm": gnorm, **aux}
-        if mixed:
-            from repro.precision import all_finite, overflow_count
-            metrics["grad_norm"] = gnorm / scale   # report unscaled
-            metrics["grads_finite"] = all_finite(grads)
-            metrics["loss_scale"] = loss_scale_value(new_opt)
-            metrics["overflow_count"] = overflow_count(new_opt)
+        with jax.named_scope("grad_norm"):
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                 for g in jax.tree.leaves(grads)))
+            metrics = {"loss": loss, "grad_norm": gnorm, **aux}
+            if mixed:
+                from repro.precision import all_finite, overflow_count
+                metrics["grad_norm"] = gnorm / scale   # report unscaled
+                metrics["grads_finite"] = all_finite(grads)
+                metrics["loss_scale"] = loss_scale_value(new_opt)
+                metrics["overflow_count"] = overflow_count(new_opt)
         return new_params, new_opt, metrics
 
     def init_fn(rng):

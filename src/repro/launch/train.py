@@ -17,13 +17,21 @@ on overflow, grow it back after clean steps). The live `loss_scale` and
 
   PYTHONPATH=src python -m repro.launch.train --arch bert-large --steps 30 \
       --precision fp16 --metrics /tmp/fp16.jsonl
+
+Every phase of set-up and of each step is a host span of
+`repro.metrics.trace` (`train.setup`, `train.init`, `train.data`;
+`train.step`, `train.input`, `train.to_device`, `train.compile`,
+`train.dispatch`, `train.wait`, `train.fetch`). Each JSONL line carries its
+step's `input_ms`, `dispatch_ms` and `wait_ms` from those spans, and
+--metrics also turns tracing on: a profile captured from the running
+process (`jax.profiler`, TensorBoard's capture) then shows the spans beside
+the device's ops.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
-import time
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +43,7 @@ from repro.core.optim import adamw, lamb, lans
 from repro.core.schedules import warmup_hold_decay, warmup_linear_decay
 from repro.data.corpus import SyntheticCorpus, lm_batch_iterator, mlm_batch_iterator
 from repro.data.sharding import ShardSpec
+from repro.metrics import trace
 from repro import precision as prec
 
 
@@ -122,93 +131,120 @@ class Trainer:
     opt_state: object
     data: object
     compiled: object = None     # the AOT-compiled step, set by train_steps
-    compile_s: float = 0.0
+    compile_s: float = 0.0      # the `train.compile` span's seconds
+    step: int = 0               # steps taken
 
 
 def make_trainer(args, devices=None) -> Trainer:
     """Config, optimizer, data-parallel mesh, initial params and state.
 
     The mesh is (data=len(devices), model=1) over `devices`, all local
-    devices by default."""
-    arch = reduced_arch(args.arch) if args.reduced else get_arch(args.arch)
-    if args.reduced:
-        args.seq = min(args.seq, arch.cfg.max_pos if arch.kind == "bert"
-                       else getattr(arch.cfg, "max_seq", args.seq))
+    devices by default. Timed as spans `train.setup`, `train.init` (until
+    the state is ready on the devices) and `train.data`."""
+    with trace.span("train.setup"):
+        arch = reduced_arch(args.arch) if args.reduced else get_arch(args.arch)
+        if args.reduced:
+            args.seq = min(args.seq, arch.cfg.max_pos if arch.kind == "bert"
+                           else getattr(arch.cfg, "max_seq", args.seq))
 
-    warm = max(1, int(args.steps * args.warmup_frac))
-    hold = int(args.steps * args.hold_frac)
-    if args.schedule == "hold":
-        sched = warmup_hold_decay(args.lr, args.steps + 1, warm, hold)
-    elif args.schedule == "linear":
-        sched = warmup_linear_decay(args.lr, args.steps + 1, warm)
-    else:
-        sched = lambda _: jnp.asarray(args.lr, jnp.float32)
-    policy = prec.get_policy(args.precision)
-    tx = make_optimizer(args.optimizer, sched, policy=policy)
-    if policy.wants_wrapper:
-        arch = dataclasses.replace(arch, cfg=policy.apply_to_cfg(arch.cfg))
+        warm = max(1, int(args.steps * args.warmup_frac))
+        hold = int(args.steps * args.hold_frac)
+        if args.schedule == "hold":
+            sched = warmup_hold_decay(args.lr, args.steps + 1, warm, hold)
+        elif args.schedule == "linear":
+            sched = warmup_linear_decay(args.lr, args.steps + 1, warm)
+        else:
+            sched = lambda _: jnp.asarray(args.lr, jnp.float32)
+        policy = prec.get_policy(args.precision)
+        tx = make_optimizer(args.optimizer, sched, policy=policy)
+        if policy.wants_wrapper:
+            arch = dataclasses.replace(arch, cfg=policy.apply_to_cfg(arch.cfg))
 
-    # One step builder for every entry point: build_train_step owns the
-    # mixed-precision wiring (master-weight wrapper, loss scaling, metrics).
-    from repro.distributed.steps import build_train_step
-    from repro.launch.mesh import make_local_mesh
+        # One step builder for every entry point: build_train_step owns the
+        # mixed-precision wiring (master-weight wrapper, loss scaling,
+        # metrics).
+        from repro.distributed.steps import build_train_step
+        from repro.launch.mesh import make_local_mesh
 
-    devices = list(devices) if devices is not None else jax.devices()
-    if args.batch % len(devices):
-        raise ValueError(f"--batch {args.batch} does not split evenly over "
-                         f"{len(devices)} devices")
-    mesh = make_local_mesh(data=len(devices), model=1, devices=devices)
-    step_fn, init_fn, specs_for = build_train_step(
-        arch.loss_fn, tx, mesh, param_init_fn=arch.init, policy=policy)
-    params, opt_state = init_fn(jax.random.PRNGKey(args.seed))
-    pspec, ospec = specs_for(params, opt_state)
-    data = make_data(arch, batch=args.batch, seq=args.seq, seed=args.seed)
+        devices = list(devices) if devices is not None else jax.devices()
+        if args.batch % len(devices):
+            raise ValueError(f"--batch {args.batch} does not split evenly "
+                             f"over {len(devices)} devices")
+        mesh = make_local_mesh(data=len(devices), model=1, devices=devices)
+        step_fn, init_fn, specs_for = build_train_step(
+            arch.loss_fn, tx, mesh, param_init_fn=arch.init, policy=policy)
+        with trace.span("train.init"):
+            params, opt_state = jax.block_until_ready(
+                init_fn(jax.random.PRNGKey(args.seed)))
+        pspec, ospec = specs_for(params, opt_state)
+        with trace.span("train.data"):
+            data = make_data(arch, batch=args.batch, seq=args.seq,
+                             seed=args.seed)
     return Trainer(args, arch, sched, policy, mesh, step_fn, pspec, ospec,
                    params, opt_state, data)
 
 
 def train_steps(tr: Trainer, steps: int, logger=None) -> dict:
-    """Run `steps` steps. The first call compiles the step ahead of time
-    (its seconds land in tr.compile_s); every step's wall time ends when
-    its params, state and metrics are ready on the device.
+    """Run `steps` steps, each a span `train.step` holding the spans of its
+    phases (see `repro.metrics.trace`). The first call compiles the step
+    ahead of time, in span `train.compile`, whose seconds land in
+    tr.compile_s; a step's `step_s` is its dispatch and its wait, until its
+    params, state and metrics are ready on the device.
 
     Returns {"losses": [...], "step_s": [...]}."""
     from repro.distributed.steps import jit_train_step
 
-    args, policy = tr.args, tr.policy
     losses, step_s = [], []
     with tr.mesh:
         for i in range(steps):
-            batch = {k: jnp.asarray(v) for k, v in next(tr.data).items()}
-            if tr.compiled is None:
-                t0 = time.perf_counter()
-                tr.compiled = jit_train_step(
-                    tr.step_fn, tr.mesh, tr.pspec, tr.ospec, batch).lower(
-                        tr.params, tr.opt_state, batch).compile()
-                tr.compile_s = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            tr.params, tr.opt_state, metrics = jax.block_until_ready(
-                tr.compiled(tr.params, tr.opt_state, batch))
-            step_s.append(time.perf_counter() - t0)
-            losses.append(float(metrics["loss"]))
-            if logger is None:
-                continue
-            extra = {}
-            if policy.wants_wrapper:
-                extra = {"loss_scale": metrics["loss_scale"],
-                         "overflow_count": metrics["overflow_count"]}
-            logger.log(i + 1, loss=metrics["loss"],
-                       lr=tr.sched(jnp.asarray(i)), **extra)
-            if (i + 1) % args.log_every == 0 or i == 0:
-                ls_txt = (f"  scale {float(extra['loss_scale']):.0f}"
-                          f"  ovf {int(extra['overflow_count'])}"
-                          if extra else "")
-                print(f"step {i+1:5d}  loss {losses[-1]:.4f}  "
-                      f"(ema {logger.smoothed_loss:.4f})  "
-                      f"lr {float(tr.sched(jnp.asarray(i))):.2e}  "
-                      f"{sum(step_s) / len(step_s):.2f}s/step{ls_txt}",
-                      flush=True)
+            with trace.span("train.step", step=tr.step):
+                with trace.span("train.input") as inp:
+                    host = next(tr.data)
+                with trace.span("train.to_device"):
+                    batch = {k: jnp.asarray(v) for k, v in host.items()}
+                if tr.compiled is None:
+                    with trace.span("train.compile") as comp:
+                        tr.compiled = jit_train_step(
+                            tr.step_fn, tr.mesh, tr.pspec, tr.ospec,
+                            batch).lower(tr.params, tr.opt_state,
+                                         batch).compile()
+                    tr.compile_s = comp.seconds
+                    trace.keep_program("train.step", tr.compiled)
+                with trace.span("train.dispatch") as dispatch:
+                    out = tr.compiled(tr.params, tr.opt_state, batch)
+                with trace.span("train.wait") as wait:
+                    tr.params, tr.opt_state, metrics = jax.block_until_ready(
+                        out)
+                step_s.append(dispatch.seconds + wait.seconds)
+                tr.step += 1
+                with trace.span("train.fetch"):
+                    losses.append(float(metrics["loss"]))
+                    if logger is not None:
+                        _log(tr, logger, i, metrics, losses, step_s,
+                             input_ms=1e3 * inp.seconds,
+                             dispatch_ms=1e3 * dispatch.seconds,
+                             wait_ms=1e3 * wait.seconds)
     return {"losses": losses, "step_s": step_s}
+
+
+def _log(tr: Trainer, logger, i: int, metrics, losses, step_s, **spans_ms):
+    """One JSONL line for step i, and every --log-every steps a console
+    line."""
+    extra = {}
+    if tr.policy.wants_wrapper:
+        extra = {"loss_scale": metrics["loss_scale"],
+                 "overflow_count": metrics["overflow_count"]}
+    logger.log(i + 1, loss=metrics["loss"], lr=tr.sched(jnp.asarray(i)),
+               **extra, **spans_ms)
+    if (i + 1) % tr.args.log_every == 0 or i == 0:
+        ls_txt = (f"  scale {float(extra['loss_scale']):.0f}"
+                  f"  ovf {int(extra['overflow_count'])}"
+                  if extra else "")
+        print(f"step {i+1:5d}  loss {losses[-1]:.4f}  "
+              f"(ema {logger.smoothed_loss:.4f})  "
+              f"lr {float(tr.sched(jnp.asarray(i))):.2e}  "
+              f"{sum(step_s) / len(step_s):.2f}s/step{ls_txt}",
+              flush=True)
 
 
 def main(argv=None):
@@ -216,6 +252,8 @@ def main(argv=None):
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
+    if args.metrics:
+        trace.enable(True)
     tr = make_trainer(args)
     from repro.metrics import MetricsLogger
 

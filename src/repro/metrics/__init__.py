@@ -1,3 +1,4 @@
+from repro.metrics import trace
 from repro.metrics.logger import MetricsLogger, read_metrics
 
-__all__ = ["MetricsLogger", "read_metrics"]
+__all__ = ["MetricsLogger", "read_metrics", "trace"]

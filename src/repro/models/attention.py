@@ -97,6 +97,7 @@ def _mask_logits(logits, q_pos, k_pos, *, causal, window, kv_valid_len=None):
     return jnp.where(mask, logits, NEG_INF)
 
 
+@jax.named_scope("attention")
 def attn_apply(
     p,
     cfg: AttnConfig,

@@ -97,45 +97,52 @@ def bert_encode(params, cfg: BertConfig, tokens, token_types=None,
     default (None) keeps the training path's contiguous 0..S-1 layout.
     """
     B, S = tokens.shape
-    x = embed_apply(params["tok_embed"], tokens, cfg.compute_dtype)
-    if positions is None:
-        x = x + params["pos_embed"].astype(cfg.compute_dtype)[None, :S]
-    else:
-        pos_ids = jnp.clip(positions, 0, cfg.max_pos - 1)
-        x = x + jnp.take(params["pos_embed"].astype(cfg.compute_dtype),
-                         pos_ids, axis=0)
-    if token_types is None:
-        token_types = jnp.zeros_like(tokens)
-    x = x + jnp.take(params["type_embed"].astype(cfg.compute_dtype),
-                     token_types, axis=0)
-    x = layernorm_apply(params["embed_ln"], x)
+    with jax.named_scope("embed"):
+        x = embed_apply(params["tok_embed"], tokens, cfg.compute_dtype)
+        if positions is None:
+            x = x + params["pos_embed"].astype(cfg.compute_dtype)[None, :S]
+        else:
+            pos_ids = jnp.clip(positions, 0, cfg.max_pos - 1)
+            x = x + jnp.take(params["pos_embed"].astype(cfg.compute_dtype),
+                             pos_ids, axis=0)
+        if token_types is None:
+            token_types = jnp.zeros_like(tokens)
+        x = x + jnp.take(params["type_embed"].astype(cfg.compute_dtype),
+                         token_types, axis=0)
+        x = layernorm_apply(params["embed_ln"], x)
 
     def layer(x, lp):
         h, _ = attn_apply(lp["attn"], cfg.attn_cfg(), x,
                           positions=positions,
                           kv_valid_len=None, compute_dtype=cfg.compute_dtype)
         x = layernorm_apply(lp["attn_ln"], x + h)
-        up = dense_apply(lp["mlp"]["up"], x, cfg.compute_dtype)
-        h = dense_apply(lp["mlp"]["down"], gelu(up), cfg.compute_dtype)
+        with jax.named_scope("ffn"):
+            up = dense_apply(lp["mlp"]["up"], x, cfg.compute_dtype)
+            h = dense_apply(lp["mlp"]["down"], gelu(up), cfg.compute_dtype)
         x = layernorm_apply(lp["mlp_ln"], x + h)
         return x, None
 
     layer = jax.checkpoint(layer,
                            policy=jax.checkpoint_policies.nothing_saveable)
-    x, _ = jax.lax.scan(layer, x, params["layers"])
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(layer, x, params["layers"])
     return x
 
 
 def bert_pretrain_logits(params, cfg: BertConfig, tokens, token_types=None):
     """Returns (mlm_logits (B,S,V), nsp_logits (B,2))."""
     h = bert_encode(params, cfg, tokens, token_types)
-    t = dense_apply(params["mlm_transform"], h, cfg.compute_dtype)
-    t = layernorm_apply(params["mlm_ln"], gelu(t))
-    mlm = jnp.einsum("bsd,vd->bsv", t.astype(cfg.compute_dtype),
-                     params["tok_embed"]["embedding"].astype(cfg.compute_dtype))
-    mlm = mlm.astype(jnp.float32) + params["mlm_bias"].astype(jnp.float32)
-    cls = jnp.tanh(dense_apply(params["pooler"], h[:, 0], cfg.compute_dtype))
-    nsp = dense_apply(params["nsp_head"], cls, cfg.compute_dtype).astype(jnp.float32)
+    with jax.named_scope("head"):
+        t = dense_apply(params["mlm_transform"], h, cfg.compute_dtype)
+        t = layernorm_apply(params["mlm_ln"], gelu(t))
+        mlm = jnp.einsum(
+            "bsd,vd->bsv", t.astype(cfg.compute_dtype),
+            params["tok_embed"]["embedding"].astype(cfg.compute_dtype))
+        mlm = mlm.astype(jnp.float32) + params["mlm_bias"].astype(jnp.float32)
+        cls = jnp.tanh(dense_apply(params["pooler"], h[:, 0],
+                                   cfg.compute_dtype))
+        nsp = dense_apply(params["nsp_head"], cls,
+                          cfg.compute_dtype).astype(jnp.float32)
     return mlm, nsp
 
 
@@ -171,14 +178,15 @@ def bert_pretrain_loss(params, cfg: BertConfig, batch):
     """batch: tokens, token_types, mlm_labels (-100 = unmasked), nsp_labels."""
     mlm_logits, nsp_logits = bert_pretrain_logits(
         params, cfg, batch["tokens"], batch.get("token_types"))
-    labels = batch["mlm_labels"]
-    valid = labels != -100
-    safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(mlm_logits, axis=-1)
-    ll = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    mlm_loss = -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1)
+    with jax.named_scope("loss"):
+        labels = batch["mlm_labels"]
+        valid = labels != -100
+        safe = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(mlm_logits, axis=-1)
+        ll = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        mlm_loss = -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1)
 
-    nsp_logp = jax.nn.log_softmax(nsp_logits, axis=-1)
-    nsp_loss = -jnp.mean(
-        jnp.take_along_axis(nsp_logp, batch["nsp_labels"][:, None], axis=-1))
+        nsp_logp = jax.nn.log_softmax(nsp_logits, axis=-1)
+        nsp_loss = -jnp.mean(jnp.take_along_axis(
+            nsp_logp, batch["nsp_labels"][:, None], axis=-1))
     return mlm_loss + nsp_loss, {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss}

@@ -203,8 +203,9 @@ def _run_slot(slot_params, cfg: DecoderConfig, mixer: str, ffn: str, x,
 
     h = rmsnorm_apply(slot_params["pre_ffn_norm"], x)
     if ffn == "mlp":
-        out = mlp_apply(slot_params["ffn"], h, activation=cfg.activation,
-                        compute_dtype=cfg.compute_dtype)
+        with jax.named_scope("ffn"):
+            out = mlp_apply(slot_params["ffn"], h, activation=cfg.activation,
+                            compute_dtype=cfg.compute_dtype)
     else:
         out, moe_aux = moe_lib.moe_apply(slot_params["ffn"], cfg.moe_cfg(), h,
                                          valid=valid,
@@ -228,12 +229,14 @@ def decoder_apply(params, cfg: DecoderConfig, tokens=None, *, embeds=None,
     path computes logits chunkwise — see chunked_lm_loss).
     """
     assert (tokens is None) != (embeds is None)
-    if embeds is None:
-        x = embed_apply(params["embed"], tokens, cfg.compute_dtype)
-        if cfg.scale_embeds:
-            x = x * jnp.asarray(jnp.sqrt(float(cfg.d_model)), cfg.compute_dtype)
-    else:
-        x = embeds.astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        if embeds is None:
+            x = embed_apply(params["embed"], tokens, cfg.compute_dtype)
+            if cfg.scale_embeds:
+                x = x * jnp.asarray(jnp.sqrt(float(cfg.d_model)),
+                                    cfg.compute_dtype)
+        else:
+            x = embeds.astype(cfg.compute_dtype)
     B, S = x.shape[:2]
     if positions is None:
         base = caches["index"] if caches is not None else 0
@@ -289,8 +292,9 @@ def decoder_apply(params, cfg: DecoderConfig, tokens=None, *, embeds=None,
         # survive to the backward pass (the config every >10B framework uses)
         step = jax.checkpoint(period_step,
                               policy=jax.checkpoint_policies.nothing_saveable)
-    x, (new_cache_stacks, aux_stacks) = jax.lax.scan(
-        step, x, (slot_param_stacks, slot_cache_stacks))
+    with jax.named_scope("layers"):
+        x, (new_cache_stacks, aux_stacks) = jax.lax.scan(
+            step, x, (slot_param_stacks, slot_cache_stacks))
 
     new_caches = None
     if caches is not None and new_cache_stacks is not None:
@@ -301,13 +305,15 @@ def decoder_apply(params, cfg: DecoderConfig, tokens=None, *, embeds=None,
 
     aux = {k: jnp.sum(v) for k, v in aux_stacks.items()}
 
-    x = rmsnorm_apply(params["final_norm"], x)
+    with jax.named_scope("head"):
+        x = rmsnorm_apply(params["final_norm"], x)
     if return_hidden:
         return x, new_caches, aux
     logits = _head_logits(params, cfg, x)
     return logits, new_caches, aux
 
 
+@jax.named_scope("head")
 def _head_logits(params, cfg: DecoderConfig, x):
     if cfg.tie_embeddings:
         logits = embed_attend(params["embed"], x, cfg.compute_dtype)
@@ -441,6 +447,7 @@ def init_paged_decoder_cache(cfg: DecoderConfig, batch: int, max_len: int,
 # losses / steps
 # --------------------------------------------------------------------------
 
+@jax.named_scope("loss")
 def lm_loss(logits, labels, *, ignore_id: int = -100,
             moe_aux: Optional[jnp.ndarray] = None, aux_weight: float = 0.01):
     """Next-token cross entropy; labels already shifted by the data pipeline."""
@@ -471,6 +478,7 @@ def chunked_lm_loss(params, cfg: DecoderConfig, hidden, labels, *,
     l_chunks = labels.reshape(B, n, chunk).swapaxes(0, 1)
 
     @jax.checkpoint
+    @jax.named_scope("loss")
     def one(h, lab):
         logits = _head_logits(params, cfg, h)
         valid = lab != ignore_id

@@ -97,71 +97,79 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
     xg = x.reshape(G, Tl, d)
     xg = maybe_constrain(xg, dp_axes or None, None, None)
 
-    router_logits = jnp.einsum(
-        "gtd,de->gte", xg.astype(jnp.float32), p["router"]["kernel"])
-    probs = jax.nn.softmax(router_logits, axis=-1)           # (G, Tl, E)
+    with jax.named_scope("moe.router"):
+        router_logits = jnp.einsum(
+            "gtd,de->gte", xg.astype(jnp.float32), p["router"]["kernel"])
+        probs = jax.nn.softmax(router_logits, axis=-1)       # (G, Tl, E)
 
-    gates_k, idx_k = jax.lax.top_k(probs, K)                 # (G, Tl, K)
-    gates_k = gates_k / jnp.maximum(
-        gates_k.sum(-1, keepdims=True), 1e-9)
+        gates_k, idx_k = jax.lax.top_k(probs, K)             # (G, Tl, K)
+        gates_k = gates_k / jnp.maximum(
+            gates_k.sum(-1, keepdims=True), 1e-9)
 
-    # Position of each (token, k) within its expert's buffer, per group.
-    sel = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)          # (G, Tl, K, E)
-    vflat = None
-    if valid is not None:
-        vg = valid.reshape(G, Tl)
-        sel = sel * vg[..., None, None].astype(sel.dtype)    # pads route nowhere
-        vflat = jnp.repeat(vg, K, axis=1)                    # (G, TlK)
-    sel_flat = sel.reshape(G, Tl * K, E)
-    position = jnp.cumsum(sel_flat, axis=1) - 1              # (G, TlK, E)
-    pos_k = jnp.take_along_axis(
-        position, idx_k.reshape(G, Tl * K)[..., None], axis=-1)[..., 0]
-    keep = pos_k < cap                                       # (G, TlK)
-    if vflat is not None:
-        keep = keep & vflat
-    pos_clipped = jnp.where(keep, pos_k, cap)                # overflow bucket
+        # Position of each (token, k) within its expert's buffer, per group.
+        sel = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)      # (G, Tl, K, E)
+        vflat = None
+        if valid is not None:
+            vg = valid.reshape(G, Tl)
+            sel = sel * vg[..., None, None].astype(sel.dtype)  # pads: nowhere
+            vflat = jnp.repeat(vg, K, axis=1)                # (G, TlK)
+        sel_flat = sel.reshape(G, Tl * K, E)
+        position = jnp.cumsum(sel_flat, axis=1) - 1          # (G, TlK, E)
+        pos_k = jnp.take_along_axis(
+            position, idx_k.reshape(G, Tl * K)[..., None], axis=-1)[..., 0]
+        keep = pos_k < cap                                   # (G, TlK)
+        if vflat is not None:
+            keep = keep & vflat
+        pos_clipped = jnp.where(keep, pos_k, cap)            # overflow bucket
 
     # Scatter dispatch: (G, E, cap+1, d), drop the overflow bucket after.
-    flat_e = idx_k.reshape(G, Tl * K)
-    x_rep = jnp.repeat(xg.astype(compute_dtype), K, axis=1)  # (G, TlK, d)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = idx_k.reshape(G, Tl * K)
+        x_rep = jnp.repeat(xg.astype(compute_dtype), K, axis=1)  # (G, TlK, d)
 
-    def scatter_group(xr, e_idx, p_idx):
-        buf = jnp.zeros((E, cap + 1, d), compute_dtype)
-        return buf.at[e_idx, p_idx].add(xr)
+        def scatter_group(xr, e_idx, p_idx):
+            buf = jnp.zeros((E, cap + 1, d), compute_dtype)
+            return buf.at[e_idx, p_idx].add(xr)
 
-    xin = jax.vmap(scatter_group)(x_rep, flat_e, pos_clipped)[:, :, :cap]
-    ep = E % max(ambient_axis_size("model"), 1) == 0 \
-        and ambient_axis_size("model") > 1
-    e_ax = "model" if ep else None
-    ff_ax = None if ep else "model"
-    xin = maybe_constrain(xin, dp_axes or None, e_ax, None, None)  # (G,E,cap,d)
+        xin = jax.vmap(scatter_group)(x_rep, flat_e, pos_clipped)[:, :, :cap]
+        ep = E % max(ambient_axis_size("model"), 1) == 0 \
+            and ambient_axis_size("model") > 1
+        e_ax = "model" if ep else None
+        ff_ax = None if ep else "model"
+        xin = maybe_constrain(xin, dp_axes or None, e_ax, None, None)
 
-    act = ACTIVATIONS[cfg.activation]
-    up = jnp.einsum("gecd,edf->gecf", xin, p["up"].astype(compute_dtype))
-    up = maybe_constrain(up, dp_axes or None, e_ax, None, ff_ax)
-    if cfg.gated:
-        g = jnp.einsum("gecd,edf->gecf", xin, p["gate"].astype(compute_dtype))
-        g = maybe_constrain(g, dp_axes or None, e_ax, None, ff_ax)
-        up = act(g) * up
-    else:
-        up = act(up)
-    yout = jnp.einsum("gecf,efd->gecd", up, p["down"].astype(compute_dtype))
-    yout = maybe_constrain(yout, dp_axes or None, e_ax, None, None)
+    with jax.named_scope("moe.experts"):
+        act = ACTIVATIONS[cfg.activation]
+        up = jnp.einsum("gecd,edf->gecf", xin, p["up"].astype(compute_dtype))
+        up = maybe_constrain(up, dp_axes or None, e_ax, None, ff_ax)
+        if cfg.gated:
+            g = jnp.einsum("gecd,edf->gecf", xin,
+                           p["gate"].astype(compute_dtype))
+            g = maybe_constrain(g, dp_axes or None, e_ax, None, ff_ax)
+            up = act(g) * up
+        else:
+            up = act(up)
+        yout = jnp.einsum("gecf,efd->gecd", up,
+                          p["down"].astype(compute_dtype))
+        yout = maybe_constrain(yout, dp_axes or None, e_ax, None, None)
 
     # Combine: gather each (token, k)'s expert output, weight, sum over K.
-    yflat = yout.reshape(G, E * cap, d)
-    gather_idx = jnp.minimum(flat_e * cap + jnp.minimum(pos_clipped, cap - 1),
-                             E * cap - 1)
-    y_tk = jnp.take_along_axis(yflat, gather_idx[..., None], axis=1)
-    w = (gates_k.reshape(G, Tl * K).astype(compute_dtype)
-         * keep.astype(compute_dtype))
-    out = (y_tk * w[..., None]).reshape(G, Tl, K, d).sum(axis=2)
-    out = out.reshape(B, S, d)
+    with jax.named_scope("moe.combine"):
+        yflat = yout.reshape(G, E * cap, d)
+        gather_idx = jnp.minimum(
+            flat_e * cap + jnp.minimum(pos_clipped, cap - 1), E * cap - 1)
+        y_tk = jnp.take_along_axis(yflat, gather_idx[..., None], axis=1)
+        w = (gates_k.reshape(G, Tl * K).astype(compute_dtype)
+             * keep.astype(compute_dtype))
+        out = (y_tk * w[..., None]).reshape(G, Tl, K, d).sum(axis=2)
+        out = out.reshape(B, S, d)
 
     # Aux load-balancing loss (mean gate fraction * mean dispatch fraction).
-    topk_mask = sel.sum(axis=2) > 0                          # (G, Tl, E)
-    density = topk_mask.astype(jnp.float32).mean(axis=(0, 1))
-    density_proxy = probs.mean(axis=(0, 1))
-    aux_loss = jnp.sum(density * density_proxy) * (E / K)
+    with jax.named_scope("moe.router"):
+        topk_mask = sel.sum(axis=2) > 0                      # (G, Tl, E)
+        density = topk_mask.astype(jnp.float32).mean(axis=(0, 1))
+        density_proxy = probs.mean(axis=(0, 1))
+        aux_loss = jnp.sum(density * density_proxy) * (E / K)
+        entropy = -(probs * jnp.log(probs + 1e-9)).sum(-1).mean()
     return out.astype(x.dtype), {"moe_aux_loss": aux_loss,
-                                 "router_entropy": -(probs * jnp.log(probs + 1e-9)).sum(-1).mean()}
+                                 "router_entropy": entropy}
