@@ -5,6 +5,8 @@
                              cast-and-apply phase-2 variant
   paged_attention_kernel     fused paged-attention decode (streams KV
                              blocks via block-table scalar prefetch)
+  flash_attention_kernel     blocked online-softmax training attention
+                             with its own backward (scores never in HBM)
   ops                        jit'd public wrappers (tiling/layout contract)
   ref                        pure-jnp oracles the kernels are tested against
 

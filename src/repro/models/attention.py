@@ -15,6 +15,7 @@ Shapes: x (B, S, d_model); cache k/v (B, max_len, n_kv, head_dim).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -22,9 +23,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import NEG_INF  # single-sourced masked-logit value
-from repro.models.common import (apply_rope, dense_apply, dense_init,
-                                 maybe_constrain, rmsnorm_apply,
-                                 rmsnorm_init, softcap)
+from repro.kernels.flash_attention_kernel import flash_attention
+from repro.models.common import (ambient_mesh, apply_rope, dense_apply,
+                                 dense_init, fit_spec, maybe_constrain,
+                                 rmsnorm_apply, rmsnorm_init, softcap)
+
+# Shortest sequence the training attention kernel takes: the shortest at
+# which a TPU v5e showed it winning (S = 512; at S = 128 its fixed cost per
+# grid step made it 4x slower than the XLA path: PERF.md, section 6).
+FLASH_MIN_SEQ = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +102,87 @@ def _mask_logits(logits, q_pos, k_pos, *, causal, window, kv_valid_len=None):
         valid = kp < kv_valid_len[:, None, None]  # (B, 1|Sq, Sk)
         mask = mask & valid[:, None]
     return jnp.where(mask, logits, NEG_INF)
+
+
+def _attend_block(q, q_pos, *, k, v, k_pos, kv_len, scale, causal, window,
+                  logit_softcap, decode, compute_dtype):
+    """XLA attention of q (B, Sq, H, hd) over k/v (B, Sk, H, hd): logits,
+    mask, float32 softmax, PV. Returns (B, Sq, H, hd) in compute_dtype."""
+    # Single-token cached decode runs its logit/PV contractions with fp32
+    # accumulation and keeps probs fp32: the (B, H, 1, K) intermediates are
+    # tiny, and it makes the Pallas paged kernel (fp32 in VREGs throughout)
+    # token-comparable to every XLA decode path — the property the
+    # paged-pallas == paged-xla differential tests pin. The OUTPUT still
+    # rounds to compute_dtype: the pools lay the same keys out at
+    # different cache rows, and that single rounding is what absorbs the
+    # sub-ulp fp32 summation-order differences so static == dense ==
+    # paged stays token-exact across layouts. The paged branch gets fp32
+    # at ANY S: its S > 1 case is the speculative-verify block, which must
+    # stay token-comparable to the Pallas kernel exactly like S == 1
+    # (other S > 1 paths are prefill, where bf16 probs are the contract).
+    acc_dtype = jnp.float32 if decode else None
+    probs_dtype = jnp.float32 if decode else compute_dtype
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=acc_dtype) * scale
+    logits = softcap(logits, logit_softcap)
+    logits = _mask_logits(
+        logits.astype(jnp.float32), q_pos, k_pos,
+        causal=causal, window=window, kv_valid_len=kv_len)
+    probs = jax.nn.softmax(logits, axis=-1).astype(probs_dtype)
+    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
+                   preferred_element_type=acc_dtype).astype(compute_dtype)
+    if decode:
+        # keep decode attention head_dim-sharded (see cache note in attn_apply)
+        o = maybe_constrain(o, "data", None, None, "model")
+    return o
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def flash_block(S: int) -> int:
+    """Query and key block of the training attention kernel at length S:
+    the whole sequence up to 1024 rows, so one grid step holds all of a
+    short sequence's scores for one head, and 1024-row blocks beyond."""
+    return min(S, 1024)
+
+
+def _flash_fits(cfg: AttnConfig, q) -> bool:
+    """Whether `attn_apply`'s no-cache self-attention of q (B, S, H, hd)
+    takes the kernel: on a TPU, bfloat16, no soft-cap or window, and S on
+    the kernel's 128-row grid and at least FLASH_MIN_SEQ."""
+    S = q.shape[1]
+    return (_on_tpu() and q.dtype == jnp.bfloat16
+            and cfg.logit_softcap is None and cfg.sliding_window is None
+            and S >= FLASH_MIN_SEQ and S % 128 == 0
+            and S % flash_block(S) == 0)
+
+
+def _flash_attend(q, k, v, *, causal, scale, block=None):
+    """`_attend_block` of the no-cache self-attention by the fused Pallas
+    kernel (kernels/flash_attention_kernel.py), which never writes the
+    (B, H, S, S) scores to memory.
+
+    q, k, v: (B, S, H, hd), k/v already repeated to H heads. block
+    defaults to `flash_block(S)`. Under a mesh of several devices the
+    kernel runs on each device's shard of the batch (and of the heads over
+    "model"), which a custom call cannot be partitioned into by itself.
+    """
+    def core(q, k, v):
+        b = block or flash_block(q.shape[1])
+        t = lambda a: a.swapaxes(1, 2)                  # (B, H, S, hd)
+        return t(flash_attention(t(q), t(k), t(v), causal=causal,
+                                 scale=scale, block_q=b, block_k=b,
+                                 interpret=not _on_tpu()))
+
+    mesh = ambient_mesh()
+    if mesh.empty or mesh.size == 1:
+        return core(q, k, v)
+    batch = tuple(a for a in ("pod", "data") if a in mesh.shape) or None
+    spec = fit_spec(mesh, q.shape, (batch, None, "model", None))
+    return jax.shard_map(core, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 @jax.named_scope("attention")
@@ -341,41 +429,21 @@ def attn_apply(
         v = jnp.repeat(v, h // kv, axis=2)
 
     causal = cfg.causal and kv_x is None and kv_cache is None
-    # Single-token cached decode runs its logit/PV contractions with fp32
-    # accumulation and keeps probs fp32: the (B, H, 1, K) intermediates are
-    # tiny, and it makes the Pallas paged kernel (fp32 in VREGs throughout)
-    # token-comparable to every XLA decode path — the property the
-    # paged-pallas == paged-xla differential tests pin. The OUTPUT still
-    # rounds to compute_dtype: the pools lay the same keys out at
-    # different cache rows, and that single rounding is what absorbs the
-    # sub-ulp fp32 summation-order differences so static == dense ==
-    # paged stays token-exact across layouts. The paged branch gets fp32
-    # at ANY S: its S > 1 case is the speculative-verify block, which must
-    # stay token-comparable to the Pallas kernel exactly like S == 1
-    # (other S > 1 paths are prefill, where bf16 probs are the contract).
     decode = attend_cached and (S == 1 or paged)
-    acc_dtype = jnp.float32 if decode else None
-    probs_dtype = jnp.float32 if decode else compute_dtype
-
-    def _attend_block(qb, q_pos_b, kv_len):
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
-                            preferred_element_type=acc_dtype) * scale
-        logits = softcap(logits, cfg.logit_softcap)
-        logits = _mask_logits(
-            logits.astype(jnp.float32), q_pos_b, k_pos,
-            causal=causal, window=cfg.sliding_window,
-            kv_valid_len=kv_len)
-        probs = jax.nn.softmax(logits, axis=-1).astype(probs_dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                       preferred_element_type=acc_dtype).astype(compute_dtype)
-        if decode:
-            # keep decode attention head_dim-sharded (see cache note above)
-            o = maybe_constrain(o, "data", None, None, "model")
-        return o
+    attend = functools.partial(
+        _attend_block, k=k, v=v, k_pos=k_pos, scale=scale, causal=causal,
+        window=cfg.sliding_window, logit_softcap=cfg.logit_softcap,
+        decode=decode, compute_dtype=compute_dtype)
 
     kv_len = kv_valid_len if attend_cached else None
     qb = cfg.q_block
-    if not attend_cached and S >= cfg.q_chunk_threshold and S % qb == 0:
+    if (cache is None and kv_x is None and kv_cache is None
+            and positions.ndim == 1 and _flash_fits(cfg, q)):
+        # Training-shaped self-attention on a TPU: the fused kernel. A
+        # 1-D `positions` without a cache counts 0..S-1 (plus an offset), so
+        # the kernel's index order is the causal mask and no key is invalid.
+        out = _flash_attend(q, k, v, causal=causal, scale=scale)
+    elif not attend_cached and S >= cfg.q_chunk_threshold and S % qb == 0:
         # remat-chunked query blocks: live logits bounded to (B,H,qb,S) and
         # the backward pass recomputes per-block probs instead of saving them.
         q_blocks = q.reshape(B, S // qb, qb, h, hd).swapaxes(0, 1)
@@ -383,11 +451,11 @@ def attn_apply(
             qpos_blocks = q_pos.reshape(B, S // qb, qb).swapaxes(0, 1)
         else:
             qpos_blocks = q_pos.reshape(S // qb, qb)
-        blk = jax.checkpoint(lambda qq, pp: _attend_block(qq, pp, kv_len))
+        blk = jax.checkpoint(lambda qq, pp: attend(qq, pp, kv_len=kv_len))
         out = jax.lax.map(lambda args: blk(*args), (q_blocks, qpos_blocks))
         out = out.swapaxes(0, 1).reshape(B, S, h, hd)
     else:
-        out = _attend_block(q, q_pos, kv_len)
+        out = attend(q, q_pos, kv_len=kv_len)
     out = out.reshape(B, S, h * hd)
     out = dense_apply(p["wo"], out, compute_dtype)
     return out, new_cache

@@ -15,6 +15,12 @@ import jax
 import jax.numpy as jnp
 
 
+def ambient_mesh():
+    """The mesh of the enclosing `with mesh:` block (`.empty` outside one)."""
+    from jax._src.mesh import thread_resources
+    return thread_resources.env.physical_mesh
+
+
 def maybe_constrain(x, *spec_axes):
     """with_sharding_constraint against the AMBIENT mesh, if any.
 
@@ -23,12 +29,18 @@ def maybe_constrain(x, *spec_axes):
     "data"); in local/unmeshed runs it is a no-op. Axes missing from the
     mesh or non-divisible dims degrade to None for that dim.
     """
-    from jax._src.mesh import thread_resources
-    from jax.sharding import PartitionSpec
-
-    mesh = thread_resources.env.physical_mesh
+    mesh = ambient_mesh()
     if mesh.empty:
         return x
+    return jax.lax.with_sharding_constraint(
+        x, fit_spec(mesh, x.shape, spec_axes))
+
+
+def fit_spec(mesh, shape, spec_axes):
+    """PartitionSpec of `spec_axes` on `mesh` for an array of `shape`: an
+    axis the mesh lacks, or that does not divide its dim, becomes None."""
+    from jax.sharding import PartitionSpec
+
     names = set(mesh.axis_names)
 
     def ok(dim, axis):
@@ -42,15 +54,12 @@ def maybe_constrain(x, *spec_axes):
             size *= mesh.shape[a]
         return axis if dim % size == 0 else None
 
-    fixed = PartitionSpec(*(ok(d, a) for d, a in zip(x.shape, spec_axes)))
-    return jax.lax.with_sharding_constraint(x, fixed)
+    return PartitionSpec(*(ok(d, a) for d, a in zip(shape, spec_axes)))
 
 
 def ambient_axis_size(name: str) -> int:
     """Size of a named axis in the ambient mesh (1 if absent/unmeshed)."""
-    from jax._src.mesh import thread_resources
-
-    mesh = thread_resources.env.physical_mesh
+    mesh = ambient_mesh()
     if mesh.empty or name not in mesh.axis_names:
         return 1
     return mesh.shape[name]

@@ -36,6 +36,7 @@ EXPECTED_MODULE_MARKERS = {
     "test_docs.py": set(),
     "test_encdec_serving.py": {"serving", "encdec"},
     "test_exactness_envelope.py": {"serving", "sharded"},
+    "test_flash_attention.py": {"kernels"},
     "test_fused_integration.py": set(),
     "test_hlo_cost.py": set(),
     "test_kernels.py": {"kernels"},

@@ -11,14 +11,17 @@ this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import lamb_kernel, lans_kernel, ops
 from repro.kernels.paged_attention_kernel import paged_attention_fused
+from repro.models import attention
 
 pytestmark = pytest.mark.kernels
 
@@ -125,3 +128,51 @@ def test_paged_attention_fused_compiles(one_chip, no_cache, S):
         ((c["n_blocks"], bs), I32), ((B, c["nb"]), I32), ((B, S), I32),
         ((B,), I32), sharding=one_chip)
     assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+# Training attention through the kernel path, at the sequence lengths of
+# bert-large phase 2 (bidirectional MHA, S 512: one block, so one backward
+# kernel) and granite (causal GQA 3:1, S 4096: dK/dV and dQ kernels) with
+# few heads; the last case shards the batch over a data=4 mesh.
+# (S, heads, kv heads, causal, batch, data-parallel devices, kernel calls:
+# forward, recompute, backward)
+FLASH = {
+    "bidirectional_512": (512, 2, 2, False, 1, 1, 3),
+    "causal_gqa_4096": (4096, 3, 1, True, 1, 1, 4),
+    "bidirectional_512_data4": (512, 2, 2, False, 8, 4, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH))
+def test_training_attention_kernel_compiles(topo, one_chip, no_cache,
+                                            monkeypatch, case):
+    """One remat'd layer's attention, forward and backward: the kernel's
+    forward, its recompute and its backward kernels, all in the
+    `attention` scope and none behind an all-gather of q/k/v."""
+    S, h, kv, causal, B, data, n_calls = FLASH[case]
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = attention.AttnConfig(d_model=h * 64, n_heads=h, n_kv_heads=kv,
+                               head_dim=64, causal=causal, rope=causal)
+    layer = jax.checkpoint(lambda p, x: attention.attn_apply(p, cfg, x)[0],
+                           policy=jax.checkpoint_policies.nothing_saveable)
+
+    def step(p, x):
+        return jax.value_and_grad(
+            lambda p, x: jnp.sum(layer(p, x).astype(F32)), argnums=(0, 1))(p, x)
+
+    mesh = Mesh(np.array(topo.devices[:data]).reshape(data, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: attention.attn_init(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((B, S, h * 64), BF16, sharding=NamedSharding(
+        mesh, PartitionSpec("data")))
+    with mesh:
+        hlo = jax.jit(step).lower(params, x).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == n_calls
+    assert all(re.search(r'op_name="[^"]*[/(]attention[/)]', line)
+               for line in calls)
+    assert "all-gather" not in hlo
