@@ -1,4 +1,6 @@
-"""Architecture registry: the 10 assigned archs + the paper's bert-large."""
+"""Architecture registry: the 10 assigned archs, the paper's bert-large,
+and moonlight-16b-a3b (latent attention and expert-parallel MoE, trained
+only: its mixer has no decode cache)."""
 from __future__ import annotations
 
 import dataclasses
@@ -19,9 +21,11 @@ _MODULES = {
     "mamba2-130m": "mamba2_130m",
     "gemma2-2b": "gemma2_2b",
     "bert-large": "bert_large",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
-ASSIGNED = [k for k in _MODULES if k != "bert-large"]
+ASSIGNED = [k for k in _MODULES
+            if k not in ("bert-large", "moonlight-16b-a3b")]
 
 
 def get_arch(name: str) -> Arch:

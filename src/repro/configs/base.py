@@ -80,11 +80,13 @@ class Arch:
                     params, self.cfg, return_hidden=True, **kw)
                 loss = dec_lib.chunked_lm_loss(
                     params, self.cfg, hidden, batch["labels"],
-                    moe_aux=aux.get("moe_aux_loss"))
+                    moe_aux=aux.get("moe_aux_loss"),
+                    aux_weight=self.cfg.aux_weight)
             else:
                 logits, _, aux = dec_lib.decoder_apply(params, self.cfg, **kw)
                 loss = dec_lib.lm_loss(logits, batch["labels"],
-                                       moe_aux=aux.get("moe_aux_loss"))
+                                       moe_aux=aux.get("moe_aux_loss"),
+                                       aux_weight=self.cfg.aux_weight)
             return loss, {"router_entropy": aux.get("router_entropy", 0.0)}
         if self.kind == "encdec":
             logits = ed_lib.encdec_apply(params, self.cfg,
@@ -276,7 +278,7 @@ def reduced_decoder(cfg: dec_lib.DecoderConfig, **over) -> dec_lib.DecoderConfig
     """Smoke-test variant: one superblock period x2, d_model<=256, <=4 experts."""
     n_slots = len(cfg.superblock)
     small = dict(
-        n_layers=max(2, n_slots) if n_slots > 1 else 2,
+        n_layers=(max(2, n_slots) if n_slots > 1 else 2) + cfg.first_k_dense,
         d_model=256,
         n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 2),
@@ -285,6 +287,12 @@ def reduced_decoder(cfg: dec_lib.DecoderConfig, **over) -> dec_lib.DecoderConfig
         vocab=1024,
         n_experts=min(cfg.n_experts, 4),
         top_k=min(cfg.top_k, 2),
+        moe_d_ff=cfg.moe_d_ff and 128,
+        router_width=cfg.router_width and min(cfg.router_width, 4),
+        kv_lora_rank=cfg.kv_lora_rank and 64,
+        qk_nope_head_dim=cfg.qk_nope_head_dim and 32,
+        qk_rope_head_dim=cfg.qk_rope_head_dim and 16,
+        v_head_dim=cfg.v_head_dim and 32,
         mamba_d_inner=512 if cfg.mamba_d_inner else None,
         mamba_headdim=64,
         mamba_dstate=32,
@@ -294,7 +302,9 @@ def reduced_decoder(cfg: dec_lib.DecoderConfig, **over) -> dec_lib.DecoderConfig
         param_dtype=jnp.float32,  # smoke numerics even for bf16 prod configs
     )
     small.update(over)
-    # superblock must still divide n_layers
-    if small["n_layers"] % n_slots != 0:
-        small["n_layers"] = n_slots * max(1, small["n_layers"] // n_slots)
+    # superblock must still divide the stacked layers
+    stacked = small["n_layers"] - cfg.first_k_dense
+    if stacked % n_slots != 0:
+        small["n_layers"] = (n_slots * max(1, stacked // n_slots)
+                             + cfg.first_k_dense)
     return dataclasses.replace(cfg, **small)

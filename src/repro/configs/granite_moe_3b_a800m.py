@@ -1,5 +1,5 @@
 """granite-moe-3b-a800m [moe] — 32L d_model=1536 24H (GQA kv=8) d_ff=512
-vocab=49155, MoE 40 experts top-8.  [hf:ibm-granite/granite-3.0-1b-a400m-base]"""
+vocab=49155, MoE 40 experts top-8.  [hf:ibm-granite/granite-3.0-3b-a800m-base]"""
 from repro.configs.base import Arch
 from repro.models.decoder import DecoderConfig
 
@@ -24,6 +24,6 @@ ARCH = Arch(
     name="granite-moe-3b-a800m",
     kind="decoder",
     cfg=CONFIG,
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base",
+    source="https://huggingface.co/ibm-granite/granite-3.0-3b-a800m-base/blob/main/config.json",
     notes="40 experts % 16 != 0: expert stacks shard the ff dim over model.",
 )

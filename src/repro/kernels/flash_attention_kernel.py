@@ -14,6 +14,11 @@ di = rowsum(dO * O)) are then (1, block) rows, stored lane-dense as
 on operands laid out for it outside the kernel (q, v and dO go in both as
 (S, hd) and as (hd, S); the output and dQ come out as (hd, S)).
 
+Widths. Queries and keys share one width and values may have another, as
+latent attention (MLA) has them: keys 192 wide, values 128. Every tile of
+q, k, dQ and dK is as wide as the keys, every tile of v, dO, the output
+and dV as wide as the values; with equal widths both are the same blocks.
+
 The keys are centred over the sequence before the kernel. Every score of
 a query moves by the same amount, which the softmax cancels, so nothing
 else changes; but dQ_i = sum_j dS_ij k_j no longer carries the keys'
@@ -80,7 +85,7 @@ def _fwd_kernel(qT_ref, k_ref, vT_ref, oT_ref, lse_ref, m_sc, l_sc, acc_sc,
         l_sc[...] = alpha * l_sc[...] + jnp.sum(pT, axis=0, keepdims=True)
         acc_sc[...] = alpha * acc_sc[...] + jnp.dot(
             vT_ref[0, 0], pT.astype(vT_ref.dtype),
-            preferred_element_type=F32)                         # (hd, bq)
+            preferred_element_type=F32)                         # (dv, bq)
         m_sc[...] = m_new
 
     if causal:
@@ -168,11 +173,13 @@ def _dq_kernel(qT_ref, k_ref, kT_ref, v_ref, doT_ref, lse_ref, di_ref,
         dqT_ref[0, 0] = dq_sc[...].astype(dqT_ref.dtype)
 
 
-def _specs(causal, bq, bk, hd, *, keys_inner):
+def _specs(causal, bq, bk, dk, dv, *, keys_inner):
     """BlockSpecs over a (B, H, nq|nk, nk|nq) grid for the operand kinds:
-    query rows (bq, hd), query columns (hd, bq), query statistics (1, bq),
-    key rows (bk, hd) and key columns (hd, bk). A causal grid's skipped
-    steps keep the block of the nearest step that runs."""
+    query rows (bq, dk), query columns (dk, bq), query statistics (1, bq),
+    key rows (bk, dk), key columns (dk, bk), value rows (bk, dv), value
+    columns (dv, bk), output rows (bq, dv) and output columns (dv, bq). A
+    causal grid's skipped steps keep the block of the nearest step that
+    runs."""
     def ij(g2, g3):
         i, j = (g2, g3) if keys_inner else (g3, g2)
         if causal and keys_inner:      # last key block query block i needs
@@ -194,11 +201,15 @@ def _specs(causal, bq, bk, hd, *, keys_inner):
         return b, h, 0, ij(g2, g3)[1]
 
     return {
-        "q_rows": pl.BlockSpec((1, 1, bq, hd), q_rows),
-        "q_cols": pl.BlockSpec((1, 1, hd, bq), q_cols),
+        "q_rows": pl.BlockSpec((1, 1, bq, dk), q_rows),
+        "q_cols": pl.BlockSpec((1, 1, dk, bq), q_cols),
         "q_stat": pl.BlockSpec((1, 1, 1, bq), q_cols),
-        "k_rows": pl.BlockSpec((1, 1, bk, hd), k_rows),
-        "k_cols": pl.BlockSpec((1, 1, hd, bk), k_cols),
+        "k_rows": pl.BlockSpec((1, 1, bk, dk), k_rows),
+        "k_cols": pl.BlockSpec((1, 1, dk, bk), k_cols),
+        "v_rows": pl.BlockSpec((1, 1, bk, dv), k_rows),
+        "v_cols": pl.BlockSpec((1, 1, dv, bk), k_cols),
+        "o_rows": pl.BlockSpec((1, 1, bq, dv), q_rows),
+        "o_cols": pl.BlockSpec((1, 1, dv, bq), q_cols),
     }
 
 
@@ -214,20 +225,21 @@ _PARAMS = pltpu.CompilerParams(
 
 
 def _forward(qT, k, vT, *, causal, scale, bq, bk, interpret):
-    """(oT (B, H, hd, S) in qT's dtype, lse (B, H, 1, S) float32)."""
-    B, H, hd, S = qT.shape
+    """(oT (B, H, dv, S) in qT's dtype, lse (B, H, 1, S) float32)."""
+    B, H, dk, S = qT.shape
+    dv = vT.shape[2]
     nq, nk = S // bq, S // bk
-    sp = _specs(causal, bq, bk, hd, keys_inner=True)
+    sp = _specs(causal, bq, bk, dk, dv, keys_inner=True)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, nk=nk),
         grid=(B, H, nq, nk),
-        in_specs=[sp["q_cols"], sp["k_rows"], sp["k_cols"]],
-        out_specs=[sp["q_cols"], sp["q_stat"]],
-        out_shape=[jax.ShapeDtypeStruct((B, H, hd, S), qT.dtype),
+        in_specs=[sp["q_cols"], sp["k_rows"], sp["v_cols"]],
+        out_specs=[sp["o_cols"], sp["q_stat"]],
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv, S), qT.dtype),
                    jax.ShapeDtypeStruct((B, H, 1, S), F32)],
         scratch_shapes=[pltpu.VMEM((1, bq), F32), pltpu.VMEM((1, bq), F32),
-                        pltpu.VMEM((hd, bq), F32)],
+                        pltpu.VMEM((dv, bq), F32)],
         compiler_params=_PARAMS,
         interpret=interpret,
         name="flash_attention_fwd",
@@ -236,25 +248,28 @@ def _forward(qT, k, vT, *, causal, scale, bq, bk, interpret):
 
 def _backward(q, qT, k, kT, v, do, doT, lse, di, *, causal, scale, bq, bk,
               interpret):
-    """(dq, dk, dv), each (B, H, S, hd) in q's dtype."""
-    B, H, S, hd = q.shape
+    """(dq, dk, dv): dq and dk (B, H, S, dk), dv (B, H, S, dv), in q's
+    dtype."""
+    B, H, S, wk = q.shape
+    wv = v.shape[3]
     nq, nk = S // bq, S // bk
     dt = q.dtype
-    kv = _specs(causal, bq, bk, hd, keys_inner=False)
+    kv = _specs(causal, bq, bk, wk, wv, keys_inner=False)
     fused = nk == 1
-    rows = jax.ShapeDtypeStruct((B, H, S, hd), dt)
-    cols = jax.ShapeDtypeStruct((B, H, hd, S), dt)
+    k_rows = jax.ShapeDtypeStruct((B, H, S, wk), dt)
+    v_rows = jax.ShapeDtypeStruct((B, H, S, wv), dt)
+    cols = jax.ShapeDtypeStruct((B, H, wk, S), dt)
     out = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, nq=nq, fused=fused),
         grid=(B, H, nk, nq),
         in_specs=[kv["q_cols"], kv["q_rows"], kv["k_rows"], kv["k_cols"],
-                  kv["k_rows"], kv["q_cols"], kv["q_rows"], kv["q_stat"],
+                  kv["v_rows"], kv["o_cols"], kv["o_rows"], kv["q_stat"],
                   kv["q_stat"]],
-        out_specs=[kv["k_rows"], kv["k_rows"]] + ([kv["q_cols"]] if fused
+        out_specs=[kv["k_rows"], kv["v_rows"]] + ([kv["q_cols"]] if fused
                                                    else []),
-        out_shape=[rows, rows] + ([cols] if fused else []),
-        scratch_shapes=[pltpu.VMEM((bk, hd), F32), pltpu.VMEM((bk, hd), F32)],
+        out_shape=[k_rows, v_rows] + ([cols] if fused else []),
+        scratch_shapes=[pltpu.VMEM((bk, wk), F32), pltpu.VMEM((bk, wv), F32)],
         compiler_params=_PARAMS,
         interpret=interpret,
         name="flash_attention_bwd_dkv",
@@ -263,17 +278,17 @@ def _backward(q, qT, k, kT, v, do, doT, lse, di, *, causal, scale, bq, bk,
         dk, dv, dqT = out
     else:
         dk, dv = out
-        qk = _specs(causal, bq, bk, hd, keys_inner=True)
+        qk = _specs(causal, bq, bk, wk, wv, keys_inner=True)
         dqT = pl.pallas_call(
             functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, nk=nk),
             grid=(B, H, nq, nk),
             in_specs=[qk["q_cols"], qk["k_rows"], qk["k_cols"],
-                      qk["k_rows"], qk["q_cols"], qk["q_stat"],
+                      qk["v_rows"], qk["o_cols"], qk["q_stat"],
                       qk["q_stat"]],
             out_specs=qk["q_cols"],
             out_shape=cols,
-            scratch_shapes=[pltpu.VMEM((hd, bq), F32)],
+            scratch_shapes=[pltpu.VMEM((wk, bq), F32)],
             compiler_params=_PARAMS,
             interpret=interpret,
             name="flash_attention_bwd_dq",
@@ -311,9 +326,10 @@ def flash_attention(q, k, v, *, causal: bool, scale: float,
                     interpret: Optional[bool] = None):
     """Softmax(scale * q k^T [causal mask]) v with a fused backward.
 
-    q, k, v: (B, H, S, hd), k and v with q's heads (repeat GQA heads
-    first). S must be a multiple of block_q and block_k, and they of 128.
-    Returns (B, H, S, hd) in q's dtype. interpret: Pallas interpret mode,
+    q, k: (B, H, S, dk) and v: (B, H, S, dv), k and v with q's heads
+    (repeat GQA heads first); the value width dv may differ from the key
+    width dk. S must be a multiple of block_q and block_k, and they of
+    128. Returns (B, H, S, dv) in q's dtype. interpret: Pallas interpret mode,
     None = off on a TPU, on elsewhere.
     """
     S = q.shape[2]

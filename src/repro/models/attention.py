@@ -148,13 +148,13 @@ def flash_block(S: int) -> int:
     return min(S, 1024)
 
 
-def _flash_fits(cfg: AttnConfig, q) -> bool:
-    """Whether `attn_apply`'s no-cache self-attention of q (B, S, H, hd)
-    takes the kernel: on a TPU, bfloat16, no soft-cap or window, and S on
-    the kernel's 128-row grid and at least FLASH_MIN_SEQ."""
+def _flash_fits(q, *, logit_softcap=None, sliding_window=None) -> bool:
+    """Whether a no-cache self-attention of q (B, S, H, hd) takes the
+    kernel: on a TPU, bfloat16, no soft-cap or window, and S on the
+    kernel's 128-row grid and at least FLASH_MIN_SEQ."""
     S = q.shape[1]
     return (_on_tpu() and q.dtype == jnp.bfloat16
-            and cfg.logit_softcap is None and cfg.sliding_window is None
+            and logit_softcap is None and sliding_window is None
             and S >= FLASH_MIN_SEQ and S % 128 == 0
             and S % flash_block(S) == 0)
 
@@ -164,7 +164,8 @@ def _flash_attend(q, k, v, *, causal, scale, block=None):
     kernel (kernels/flash_attention_kernel.py), which never writes the
     (B, H, S, S) scores to memory.
 
-    q, k, v: (B, S, H, hd), k/v already repeated to H heads. block
+    q, k: (B, S, H, dk), v: (B, S, H, dv), k/v already repeated to H
+    heads; the value width may differ from the key width. block
     defaults to `flash_block(S)`. Under a mesh of several devices the
     kernel runs on each device's shard of the batch (and of the heads over
     "model"), which a custom call cannot be partitioned into by itself.
@@ -438,7 +439,9 @@ def attn_apply(
     kv_len = kv_valid_len if attend_cached else None
     qb = cfg.q_block
     if (cache is None and kv_x is None and kv_cache is None
-            and positions.ndim == 1 and _flash_fits(cfg, q)):
+            and positions.ndim == 1
+            and _flash_fits(q, logit_softcap=cfg.logit_softcap,
+                            sliding_window=cfg.sliding_window)):
         # Training-shaped self-attention on a TPU: the fused kernel. A
         # 1-D `positions` without a cache counts 0..S-1 (plus an offset), so
         # the kernel's index order is the causal mask and no key is invalid.

@@ -4,11 +4,14 @@ The layer stack is described as a repeating **superblock**: a short, fixed
 list of (mixer, ffn) slots. Examples:
 
   dense / moe     : [("attn", "mlp"|"moe")]                       x n_layers
+  moonlight (MLA) : [("mla", "moe")] x (n_layers - 1), after one dense
+                    ("mla", "mlp") layer (`first_k_dense`)
   gemma2          : [("attn_local", "mlp"), ("attn", "mlp")]      x n_layers/2
   jamba (hybrid)  : [("attn", "moe"), ("mamba", "mlp"), ...]      x n_layers/8
 
 Parameters for each slot are stacked over the repeat dimension and the
-forward pass is a single `jax.lax.scan` over periods — one trace per slot
+forward pass is a single `jax.lax.scan` over periods (leading dense
+layers, stacked under "lead", run before it, unrolled) — one trace per slot
 type regardless of depth, which keeps the HLO small enough to compile 72-layer
 398B configs in the dry-run. Caches (attention KV / SSM state) are likewise
 stacked per slot and threaded through the scan as xs/ys.
@@ -24,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn_lib
 from repro.models import mamba2 as mamba_lib
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models.attention import AttnConfig, attn_apply, attn_init
 from repro.models.common import (
@@ -67,9 +71,23 @@ class DecoderConfig:
     gated_mlp: bool = True
 
     # moe
-    n_experts: int = 0
+    n_experts: int = 0                         # experts held here
     top_k: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: Optional[int] = None             # expert width; None: d_ff
+    router: str = "softmax"                    # "softmax" | "sigmoid"
+    routed_scaling: float = 1.0
+    router_width: Optional[int] = None         # experts scored; None: all
+    first_expert: int = 0                      # router index of first held
+    n_shared_experts: int = 0
+    seq_aux: bool = False                      # sequence-wise balance loss
+    aux_weight: float = 0.01                   # of the balance loss
+
+    # latent attention ("mla" slots)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # mamba slots (hybrid archs)
     mamba_d_inner: Optional[int] = None
@@ -80,6 +98,10 @@ class DecoderConfig:
     # superblock: sequence of (mixer, ffn) slot descriptors.
     #   mixer in {"attn", "attn_local", "mamba"}; ffn in {"mlp", "moe"}
     superblock: Tuple[Tuple[str, str], ...] = (("attn", "mlp"),)
+    # leading dense layers before the periods: the first slot's mixer and
+    # a d_ff-wide "mlp" each
+    first_k_dense: int = 0
+    rms_norm_eps: float = 1e-6
 
     tie_embeddings: bool = True
     scale_embeds: bool = False                 # gemma2: x *= sqrt(d_model)
@@ -94,9 +116,10 @@ class DecoderConfig:
 
     @property
     def n_periods(self) -> int:
-        assert self.n_layers % len(self.superblock) == 0, (
-            self.n_layers, self.superblock)
-        return self.n_layers // len(self.superblock)
+        stacked = self.n_layers - self.first_k_dense
+        assert stacked % len(self.superblock) == 0, (
+            self.n_layers, self.first_k_dense, self.superblock)
+        return stacked // len(self.superblock)
 
     def attn_cfg(self, local: bool) -> AttnConfig:
         return AttnConfig(
@@ -111,9 +134,21 @@ class DecoderConfig:
 
     def moe_cfg(self) -> moe_lib.MoeConfig:
         return moe_lib.MoeConfig(
-            d_model=self.d_model, d_ff=self.d_ff, n_experts=self.n_experts,
-            top_k=self.top_k, capacity_factor=self.capacity_factor,
-            activation=self.activation, gated=self.gated_mlp)
+            d_model=self.d_model, d_ff=self.moe_d_ff or self.d_ff,
+            n_experts=self.n_experts, top_k=self.top_k,
+            capacity_factor=self.capacity_factor,
+            activation=self.activation, gated=self.gated_mlp,
+            router=self.router, routed_scaling=self.routed_scaling,
+            router_width=self.router_width, first_expert=self.first_expert,
+            n_shared=self.n_shared_experts, seq_aux=self.seq_aux)
+
+    def mla_cfg(self) -> mla_lib.MlaConfig:
+        return mla_lib.MlaConfig(
+            d_model=self.d_model, n_heads=self.n_heads,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, rope_theta=self.rope_theta)
 
     def mamba_cfg(self) -> mamba_lib.MambaConfig:
         return mamba_lib.MambaConfig(
@@ -139,6 +174,8 @@ def _slot_init(rng, cfg: DecoderConfig, mixer: str, ffn: str):
                                dtype=cfg.param_dtype)
     elif mixer == "mamba":
         p["mixer"] = mamba_lib.mamba_init(ks[0], cfg.mamba_cfg(), cfg.param_dtype)
+    elif mixer == "mla":
+        p["mixer"] = mla_lib.mla_init(ks[0], cfg.mla_cfg(), cfg.param_dtype)
     else:
         raise ValueError(mixer)
     if ffn == "mlp":
@@ -169,6 +206,11 @@ def decoder_init(rng, cfg: DecoderConfig):
         slot_rngs = jax.random.split(ks[2 + si], cfg.n_periods)
         params[f"slot{si}"] = jax.vmap(
             lambda r: _slot_init(r, cfg, mixer, ffn))(slot_rngs)
+    if cfg.first_k_dense:
+        lead_rngs = jax.random.split(jax.random.fold_in(rng, 1),
+                                     cfg.first_k_dense)
+        params["lead"] = jax.vmap(lambda r: _slot_init(
+            r, cfg, cfg.superblock[0][0], "mlp"))(lead_rngs)
     return params
 
 
@@ -184,24 +226,29 @@ def _run_slot(slot_params, cfg: DecoderConfig, mixer: str, ffn: str, x,
     contributions must be suppressed (attention masks pads by their
     negative positions; mamba/moe need the explicit mask)."""
     aux = {}
-    h = rmsnorm_apply(slot_params["pre_mixer_norm"], x)
+    eps = cfg.rms_norm_eps
+    h = rmsnorm_apply(slot_params["pre_mixer_norm"], x, eps)
     if mixer in ("attn", "attn_local"):
         out, new_cache = attn_apply(
             slot_params["mixer"], cfg.attn_cfg(mixer == "attn_local"), h,
             positions=positions, cache=cache, kv_valid_len=kv_valid_len,
             compute_dtype=cfg.compute_dtype)
+    elif mixer == "mla":
+        out, new_cache = mla_lib.mla_apply(
+            slot_params["mixer"], cfg.mla_cfg(), h, positions=positions,
+            cache=cache, compute_dtype=cfg.compute_dtype)
     else:
         out, new_cache = mamba_lib.mamba_apply(
             slot_params["mixer"], cfg.mamba_cfg(), h, cache=cache,
             valid=valid, compute_dtype=cfg.compute_dtype)
     if cfg.post_block_norm:
-        out = rmsnorm_apply(slot_params["post_mixer_norm"], out)
+        out = rmsnorm_apply(slot_params["post_mixer_norm"], out, eps)
     x = x + out
 
     if ffn == "none":
         return x, new_cache, aux
 
-    h = rmsnorm_apply(slot_params["pre_ffn_norm"], x)
+    h = rmsnorm_apply(slot_params["pre_ffn_norm"], x, eps)
     if ffn == "mlp":
         with jax.named_scope("ffn"):
             out = mlp_apply(slot_params["ffn"], h, activation=cfg.activation,
@@ -212,7 +259,7 @@ def _run_slot(slot_params, cfg: DecoderConfig, mixer: str, ffn: str, x,
                                          compute_dtype=cfg.compute_dtype)
         aux.update(moe_aux)
     if cfg.post_block_norm:
-        out = rmsnorm_apply(slot_params["post_ffn_norm"], out)
+        out = rmsnorm_apply(slot_params["post_ffn_norm"], out, eps)
     x = x + out
     return x, new_cache, aux
 
@@ -293,6 +340,19 @@ def decoder_apply(params, cfg: DecoderConfig, tokens=None, *, embeds=None,
         step = jax.checkpoint(period_step,
                               policy=jax.checkpoint_policies.nothing_saveable)
     with jax.named_scope("layers"):
+        if cfg.first_k_dense:
+            if caches is not None:
+                raise NotImplementedError("leading dense layers: no cache")
+
+            def lead(lead_params, x):
+                return _run_slot(lead_params, cfg, cfg.superblock[0][0],
+                                 "mlp", x, positions, None, None, valid)[0]
+
+            if cfg.remat:
+                lead = jax.checkpoint(
+                    lead, policy=jax.checkpoint_policies.nothing_saveable)
+            for i in range(cfg.first_k_dense):
+                x = lead(jax.tree.map(lambda a: a[i], params["lead"]), x)
         x, (new_cache_stacks, aux_stacks) = jax.lax.scan(
             step, x, (slot_param_stacks, slot_cache_stacks))
 
@@ -306,7 +366,7 @@ def decoder_apply(params, cfg: DecoderConfig, tokens=None, *, embeds=None,
     aux = {k: jnp.sum(v) for k, v in aux_stacks.items()}
 
     with jax.named_scope("head"):
-        x = rmsnorm_apply(params["final_norm"], x)
+        x = rmsnorm_apply(params["final_norm"], x, cfg.rms_norm_eps)
     if return_hidden:
         return x, new_caches, aux
     logits = _head_logits(params, cfg, x)

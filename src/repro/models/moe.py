@@ -10,6 +10,17 @@ blocks see very different gradient scales than expert FFN blocks).
 
 Includes the standard auxiliary load-balancing loss (Shazeer et al.) exposed
 to the training loop.
+
+Two routers: softmax (granite, grok, jamba) and DeepSeek-V3's sigmoid
+(`noaux_tc`: a selection bias added to the scores for choosing only, the
+chosen scores renormalised and times `routed_scaling`, and the
+sequence-wise balance loss of arXiv:2412.19437 section 2.1.2), with
+optional shared experts that every token passes through. An expert layer
+may hold only some of the experts its router scores, as one chip of an
+expert-parallel group does: `n_experts` held, starting at router index
+`first_expert`, of `router_width`. It routes over all of them and
+computes only the held experts' part of the output; the other experts'
+part is left out.
 """
 from __future__ import annotations
 
@@ -19,8 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (ACTIVATIONS, ambient_axis_size, dense_apply,
-                                 dense_init, maybe_constrain)
+from repro.models.common import (ACTIVATIONS, ambient_axis_size, dense_init,
+                                 maybe_constrain, mlp_apply, mlp_init)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +43,16 @@ class MoeConfig:
     capacity_factor: float = 1.25
     activation: str = "silu"
     gated: bool = True
+    router: str = "softmax"              # "softmax" | "sigmoid"
+    routed_scaling: float = 1.0          # times the renormalised gates
+    router_width: Optional[int] = None   # experts scored; None: n_experts
+    first_expert: int = 0                # router index of the first held
+    n_shared: int = 0                    # shared experts, d_ff wide each
+    seq_aux: bool = False                # sequence-wise balance loss
+
+    @property
+    def n_routed(self) -> int:
+        return self.router_width or self.n_experts
 
 
 def moe_init(rng, cfg: MoeConfig, dtype=jnp.float32):
@@ -44,12 +65,19 @@ def moe_init(rng, cfg: MoeConfig, dtype=jnp.float32):
         return (jax.random.normal(k, (e, din, dout)) * scale).astype(dtype)
 
     p = {
-        "router": dense_init(ks[0], d, e, use_bias=False, dtype=jnp.float32),
+        "router": dense_init(ks[0], d, cfg.n_routed, use_bias=False,
+                             dtype=jnp.float32),
         "up": expert_stack(ks[1], d, f),
         "down": expert_stack(ks[2], f, d),
     }
     if cfg.gated:
         p["gate"] = expert_stack(ks[3], d, f)
+    if cfg.router == "sigmoid":
+        # the selection bias, held at zero: no gradient reaches it
+        p["router"]["bias"] = jnp.zeros((cfg.n_routed,), jnp.float32)
+    if cfg.n_shared:
+        p["shared"] = mlp_init(jax.random.fold_in(rng, 1), d,
+                               cfg.n_shared * f, gated=cfg.gated, dtype=dtype)
     return p
 
 
@@ -81,7 +109,8 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
     divisible (jamba 16e), otherwise the ff dim (grok 8e, granite 40e).
     """
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
+    E, K, R = cfg.n_experts, cfg.top_k, cfg.n_routed
+    partial = R != E or cfg.first_expert != 0
     T = B * S
     # Groups span the FULL data-parallel extent (pod x data): using "data"
     # alone replicated all expert compute across pods (pod2 dry-run showed
@@ -92,7 +121,7 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
         G //= 2
     G = max(G, 1)
     Tl = T // G
-    cap = max(1, int(cfg.capacity_factor * Tl * K / E))
+    cap = max(1, int(cfg.capacity_factor * Tl * K / R))
 
     xg = x.reshape(G, Tl, d)
     xg = maybe_constrain(xg, dp_axes or None, None, None)
@@ -100,14 +129,30 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
     with jax.named_scope("moe.router"):
         router_logits = jnp.einsum(
             "gtd,de->gte", xg.astype(jnp.float32), p["router"]["kernel"])
-        probs = jax.nn.softmax(router_logits, axis=-1)       # (G, Tl, E)
-
-        gates_k, idx_k = jax.lax.top_k(probs, K)             # (G, Tl, K)
+        if cfg.router == "sigmoid":
+            probs = jax.nn.sigmoid(router_logits)            # (G, Tl, R)
+            _, idx_k = jax.lax.top_k(probs + p["router"]["bias"], K)
+            gates_k = jnp.take_along_axis(probs, idx_k, axis=-1)
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)   # (G, Tl, R)
+            gates_k, idx_k = jax.lax.top_k(probs, K)         # (G, Tl, K)
+        chosen = idx_k                                       # router index
         gates_k = gates_k / jnp.maximum(
             gates_k.sum(-1, keepdims=True), 1e-9)
+        if cfg.routed_scaling != 1.0:
+            gates_k = gates_k * cfg.routed_scaling
 
+        # Held experts' index of each (token, k); a pair sent to an expert
+        # held elsewhere gets no one-hot, so it takes no place below.
+        held = None
+        if partial:
+            local = idx_k - cfg.first_expert
+            held = (local >= 0) & (local < E)
+            idx_k = jnp.where(held, local, -1)
         # Position of each (token, k) within its expert's buffer, per group.
         sel = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)      # (G, Tl, K, E)
+        if partial:
+            idx_k = jnp.maximum(idx_k, 0)
         vflat = None
         if valid is not None:
             vg = valid.reshape(G, Tl)
@@ -120,6 +165,8 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
         keep = pos_k < cap                                   # (G, TlK)
         if vflat is not None:
             keep = keep & vflat
+        if held is not None:
+            keep = keep & held.reshape(G, Tl * K)
         pos_clipped = jnp.where(keep, pos_k, cap)            # overflow bucket
 
     # Scatter dispatch: (G, E, cap+1, d), drop the overflow bucket after.
@@ -164,12 +211,40 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
         out = (y_tk * w[..., None]).reshape(G, Tl, K, d).sum(axis=2)
         out = out.reshape(B, S, d)
 
-    # Aux load-balancing loss (mean gate fraction * mean dispatch fraction).
+    if cfg.n_shared:
+        with jax.named_scope("ffn"), jax.named_scope("moe.shared"):
+            out = out + mlp_apply(p["shared"], x, activation=cfg.activation,
+                                  compute_dtype=compute_dtype)
+
     with jax.named_scope("moe.router"):
-        topk_mask = sel.sum(axis=2) > 0                      # (G, Tl, E)
-        density = topk_mask.astype(jnp.float32).mean(axis=(0, 1))
-        density_proxy = probs.mean(axis=(0, 1))
-        aux_loss = jnp.sum(density * density_proxy) * (E / K)
+        if cfg.seq_aux:
+            aux_loss = _sequence_balance(probs, chosen.reshape(B, S, K),
+                                         R, K)
+            probs = probs / probs.sum(-1, keepdims=True)
+        else:
+            # mean gate fraction * mean dispatch fraction over the batch
+            if partial:
+                topk_mask = jax.nn.one_hot(chosen, R, dtype=jnp.int32
+                                           ).sum(axis=2) > 0
+            else:
+                topk_mask = sel.sum(axis=2) > 0              # (G, Tl, R)
+            density = topk_mask.astype(jnp.float32).mean(axis=(0, 1))
+            density_proxy = probs.mean(axis=(0, 1))
+            aux_loss = jnp.sum(density * density_proxy) * (R / K)
         entropy = -(probs * jnp.log(probs + 1e-9)).sum(-1).mean()
     return out.astype(x.dtype), {"moe_aux_loss": aux_loss,
                                  "router_entropy": entropy}
+
+
+def _sequence_balance(scores, chosen, R: int, K: int):
+    """DeepSeek-V3's sequence-wise balance loss (arXiv:2412.19437 eq. 17-20)
+    without its weight, over every expert the router scores: per sequence,
+    sum_i f_i P_i with f_i = R / (K S) times the tokens that chose expert i
+    and P_i the mean of its score normalised over the experts; the mean
+    over the sequences. chosen: (B, S, K) router indices."""
+    B, S, _ = chosen.shape
+    scores = scores.reshape(B, S, R)
+    f = jax.nn.one_hot(chosen, R, dtype=jnp.float32).sum(axis=(1, 2))
+    f = f * (R / (K * S))                                    # (B, R)
+    P = (scores / scores.sum(-1, keepdims=True)).mean(axis=1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))

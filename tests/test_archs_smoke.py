@@ -8,7 +8,7 @@ import pytest
 from repro.configs import ASSIGNED, SHAPES, get_arch, reduced_arch
 from repro.core.optim import apply_updates, lans
 
-ALL = ASSIGNED + ["bert-large"]
+ALL = ASSIGNED + ["bert-large", "moonlight-16b-a3b"]
 
 
 def _batch(arch, rng, B=2, S=32):
@@ -124,6 +124,15 @@ def test_full_config_matches_assignment(name):
                           n_kv_heads=4, d_ff=9216, vocab=256000),
         "bert-large": dict(n_layers=24, d_model=1024, n_heads=16,
                            d_ff=4096, vocab=30522),
+        "moonlight-16b-a3b": dict(n_layers=27, first_k_dense=1,
+                                  d_model=2048, n_heads=16, d_ff=11264,
+                                  moe_d_ff=1408, vocab=163840,
+                                  kv_lora_rank=512, qk_nope_head_dim=128,
+                                  qk_rope_head_dim=64, v_head_dim=128,
+                                  n_experts=64, router_width=64, top_k=6,
+                                  n_shared_experts=2, router="sigmoid",
+                                  routed_scaling=2.446, rms_norm_eps=1e-5,
+                                  rope_theta=50000.0, tie_embeddings=False),
     }[name]
     for k, v in expected.items():
         assert getattr(arch.cfg, k) == v, (name, k, getattr(arch.cfg, k), v)
@@ -134,7 +143,7 @@ def test_param_counts_match_assigned_sizes():
     sizes = {"grok-1-314b": 314e9, "qwen2.5-14b": 14e9, "qwen2.5-32b": 32e9,
              "chameleon-34b": 34e9, "mistral-nemo-12b": 12e9,
              "jamba-1.5-large-398b": 398e9, "mamba2-130m": 130e6,
-             "gemma2-2b": 2.6e9}
+             "gemma2-2b": 2.6e9, "moonlight-16b-a3b": 16e9}
     for name, want in sizes.items():
         got = get_arch(name).param_count()
         assert 0.8 * want <= got <= 1.25 * want, (name, got, want)

@@ -38,17 +38,22 @@ CASES = {
     # keys that share a large part across positions, as BERT's key bias
     # makes them: dQ is small beside the rounding of terms that carry it
     "shared_key_part": (2, 256, 4, 4, False, 256, 4),
+    # latent attention (MLA): keys 192 wide, values 128, causal over two
+    # blocks on each axis, so every kernel takes the two widths
+    "mla_widths": (1, 512, 2, 2, True, 256, 0),
 }
+# (key width, value width) of a case where they differ from HD
+WIDTHS = {"mla_widths": (192, 128)}
 
 
-def _qkvw(B, S, h, kv, shared):
+def _qkvw(B, S, h, kv, shared, dk=HD, dv=HD):
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    q = jax.random.normal(ks[0], (B, S, h, HD)).astype(jnp.bfloat16)
+    q = jax.random.normal(ks[0], (B, S, h, dk)).astype(jnp.bfloat16)
     noise = 0.1 if shared else 1.0
-    k = (shared * jax.random.normal(ks[4], (1, 1, kv, HD))
-         + noise * jax.random.normal(ks[1], (B, S, kv, HD))).astype(jnp.bfloat16)
-    v = jax.random.normal(ks[2], (B, S, kv, HD)).astype(jnp.bfloat16)
-    w = jax.random.normal(ks[3], (B, S, h, HD)).astype(jnp.bfloat16)
+    k = (shared * jax.random.normal(ks[4], (1, 1, kv, dk))
+         + noise * jax.random.normal(ks[1], (B, S, kv, dk))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, kv, dv)).astype(jnp.bfloat16)
+    w = jax.random.normal(ks[3], (B, S, h, dv)).astype(jnp.bfloat16)
     return q, k, v, w
 
 
@@ -59,9 +64,10 @@ def test_flash_matches_xla_path(case):
     kernel is within TOL of it, and no further from it than the XLA path
     is (plus one bfloat16 epsilon)."""
     B, S, h, kv, causal, block, shared = CASES[case]
-    q, k, v, w = _qkvw(B, S, h, kv, shared)
+    dk, dv = WIDTHS.get(case, (HD, HD))
+    q, k, v, w = _qkvw(B, S, h, kv, shared, dk, dv)
     pos = jnp.arange(S)
-    scale = HD ** -0.5
+    scale = dk ** -0.5
 
     def rep(a):  # GQA as attn_apply does it: its transpose sums each group
         return jnp.repeat(a, h // kv, axis=2)

@@ -42,6 +42,7 @@ EXPECTED_MODULE_MARKERS = {
     "test_kernels.py": {"kernels"},
     "test_markers.py": set(),
     "test_metrics_and_launchers.py": set(),
+    "test_mla_moe.py": set(),
     "test_models.py": set(),
     "test_optimizers.py": set(),
     "test_paged_cache.py": {"serving", "paged"},

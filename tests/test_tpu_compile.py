@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 
 from repro.kernels import lamb_kernel, lans_kernel, ops
 from repro.kernels.paged_attention_kernel import paged_attention_fused
-from repro.models import attention
+from repro.models import attention, mla
 
 pytestmark = pytest.mark.kernels
 
@@ -176,3 +176,32 @@ def test_training_attention_kernel_compiles(topo, one_chip, no_cache,
     assert all(re.search(r'op_name="[^"]*[/(]attention[/)]', line)
                for line in calls)
     assert "all-gather" not in hlo
+
+
+def test_mla_attention_kernel_compiles(topo, one_chip, no_cache, monkeypatch):
+    """One remat'd latent-attention layer of moonlight-16b-a3b at its
+    published widths and 8,192 tokens, forward and backward: the kernel
+    takes keys 192 wide and values 128 (forward, recompute, dK/dV, dQ),
+    all in the `attention` scope, and no (S, S) array is left."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = mla.MlaConfig(d_model=2048, n_heads=16, kv_lora_rank=512,
+                        qk_nope_head_dim=128, qk_rope_head_dim=64,
+                        v_head_dim=128, rope_theta=50000.0)
+    layer = jax.checkpoint(lambda p, x: mla.mla_apply(p, cfg, x)[0],
+                           policy=jax.checkpoint_policies.nothing_saveable)
+
+    def step(p, x):
+        return jax.value_and_grad(
+            lambda p, x: jnp.sum(layer(p, x).astype(F32)), argnums=(0, 1))(p, x)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=one_chip),
+        jax.eval_shape(lambda: mla.mla_init(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), BF16, sharding=one_chip)
+    hlo = jax.jit(step).lower(params, x).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4
+    assert all(re.search(r'op_name="[^"]*[/(]attention[/)]', line)
+               for line in calls)
+    assert not re.search(r"\[(1,)?16,8192,8192\]", hlo)
