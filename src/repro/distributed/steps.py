@@ -141,18 +141,24 @@ def build_train_step(
     return step_fn, init_fn, specs_for
 
 
+def _named(mesh: Mesh, spec_tree):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def batch_sharding(mesh: Mesh, batch_like):
+    """The train step's input sharding for a batch like `batch_like` (its
+    leading dim over the mesh's batch axes): where a batch put there by
+    `jax.device_put` is taken by the compiled step without a reshard."""
+    return _named(mesh, shd.batch_pspec(batch_like, mesh))
+
+
 def jit_train_step(step_fn, mesh: Mesh, pspec, ospec, batch_like):
-    bspec = shd.batch_pspec(batch_like, mesh)
-    metr = P()  # metrics replicated
-
-    def shardings(spec_tree):
-        return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
-                            is_leaf=lambda x: isinstance(x, P))
-
     return jax.jit(
         step_fn,
-        in_shardings=(shardings(pspec), shardings(ospec), shardings(bspec)),
-        out_shardings=(shardings(pspec), shardings(ospec), None),
+        in_shardings=(_named(mesh, pspec), _named(mesh, ospec),
+                      batch_sharding(mesh, batch_like)),
+        out_shardings=(_named(mesh, pspec), _named(mesh, ospec), None),
     )
 
 

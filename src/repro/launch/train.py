@@ -18,14 +18,20 @@ on overflow, grow it back after clean steps). The live `loss_scale` and
   PYTHONPATH=src python -m repro.launch.train --arch bert-large --steps 30 \
       --precision fp16 --metrics /tmp/fp16.jsonl
 
+`train_steps` stages input one step ahead: while step i runs on the
+devices, the host takes batch i+1 from the feed and copies it into the
+compiled step's own input sharding, so the build is off the step's
+critical path. One step is in flight at a time, and a call takes exactly
+as many batches as it runs steps.
+
 Every phase of set-up and of each step is a host span of
 `repro.metrics.trace` (`train.setup`, `train.init`, `train.data`;
 `train.step`, `train.input`, `train.to_device`, `train.compile`,
 `train.dispatch`, `train.wait`, `train.fetch`). Each JSONL line carries its
-step's `input_ms`, `dispatch_ms` and `wait_ms` from those spans, and
---metrics also turns tracing on: a profile captured from the running
-process (`jax.profiler`, TensorBoard's capture) then shows the spans beside
-the device's ops.
+step's `input_ms` (the build of its own batch), `dispatch_ms` and `wait_ms`
+from those spans, and --metrics also turns tracing on: a profile captured
+from the running process (`jax.profiler`, TensorBoard's capture) then shows
+the spans beside the device's ops.
 """
 from __future__ import annotations
 
@@ -186,22 +192,30 @@ def make_trainer(args, devices=None) -> Trainer:
 
 def train_steps(tr: Trainer, steps: int, logger=None) -> dict:
     """Run `steps` steps, each a span `train.step` holding the spans of its
-    phases (see `repro.metrics.trace`). The first call compiles the step
-    ahead of time, in span `train.compile`, whose seconds land in
-    tr.compile_s; a step's `step_s` is its dispatch and its wait, until its
-    params, state and metrics are ready on the device.
+    phases (see `repro.metrics.trace`), on exactly `steps` batches taken
+    from tr.data in order.
+
+    Input is staged one step ahead (`_stage`). Batch 0 is staged in step 0,
+    before its dispatch; batch i+1 in step i, after step i is dispatched
+    and before its wait, so the host builds and copies it while step i
+    runs. The last step stages nothing: no batch crosses the end of a
+    call. Step i's outputs are waited on before step i+1 is dispatched, so
+    one step is in flight at a time.
+
+    The first call compiles the step ahead of time, in span
+    `train.compile`, whose seconds land in tr.compile_s; a step's `step_s`
+    is its dispatch and its wait, until its params, state and metrics are
+    ready on the device.
 
     Returns {"losses": [...], "step_s": [...]}."""
     from repro.distributed.steps import jit_train_step
 
     losses, step_s = [], []
+    staged = None
     with tr.mesh:
         for i in range(steps):
             with trace.span("train.step", step=tr.step):
-                with trace.span("train.input") as inp:
-                    host = next(tr.data)
-                with trace.span("train.to_device"):
-                    batch = {k: jnp.asarray(v) for k, v in host.items()}
+                batch, inp = staged if staged is not None else _stage(tr)
                 if tr.compiled is None:
                     with trace.span("train.compile") as comp:
                         tr.compiled = jit_train_step(
@@ -212,6 +226,7 @@ def train_steps(tr: Trainer, steps: int, logger=None) -> dict:
                     trace.keep_program("train.step", tr.compiled)
                 with trace.span("train.dispatch") as dispatch:
                     out = tr.compiled(tr.params, tr.opt_state, batch)
+                staged = _stage(tr) if i + 1 < steps else None
                 with trace.span("train.wait") as wait:
                     tr.params, tr.opt_state, metrics = jax.block_until_ready(
                         out)
@@ -225,6 +240,19 @@ def train_steps(tr: Trainer, steps: int, logger=None) -> dict:
                              dispatch_ms=1e3 * dispatch.seconds,
                              wait_ms=1e3 * wait.seconds)
     return {"losses": losses, "step_s": step_s}
+
+
+def _stage(tr: Trainer):
+    """The feed's next batch (span `train.input`), put by `jax.device_put`
+    straight into the compiled step's input sharding (`train.to_device`),
+    so that the call takes it as it is. Returns (batch, its input span)."""
+    from repro.distributed.steps import batch_sharding
+
+    with trace.span("train.input") as inp:
+        host = next(tr.data)
+    with trace.span("train.to_device"):
+        batch = jax.device_put(host, batch_sharding(tr.mesh, host))
+    return batch, inp
 
 
 def _log(tr: Trainer, logger, i: int, metrics, losses, step_s, **spans_ms):

@@ -32,12 +32,17 @@ Span names used by the launcher (`repro.launch.train`):
       train.init        parameters, precision cast, optimizer init, ready
       train.data        the batch feed
     train.step        one step of train_steps (step number attached)
-      train.input       next() of the batch feed
-      train.to_device   the batch's host-to-device copy
+      train.input       next() of the batch feed, for this step or, staged,
+                        the next
+      train.to_device   that batch's copy into the step's input sharding
       train.compile     the first call's lower and compile
       train.dispatch    the compiled step's call, until it returns
       train.wait        block_until_ready on its outputs
       train.fetch       the loss to the host, and the metrics logger
+
+Input is staged one step ahead: in a call of n steps, step 0 holds the
+input spans of batch 0 before its dispatch, and each step i < n - 1 those
+of batch i + 1 after its dispatch and before its wait.
 """
 from __future__ import annotations
 

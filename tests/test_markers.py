@@ -39,6 +39,7 @@ EXPECTED_MODULE_MARKERS = {
     "test_flash_attention.py": {"kernels"},
     "test_fused_integration.py": set(),
     "test_hlo_cost.py": set(),
+    "test_input_staging.py": set(),
     "test_kernels.py": {"kernels"},
     "test_markers.py": set(),
     "test_metrics_and_launchers.py": set(),

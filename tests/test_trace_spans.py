@@ -118,11 +118,20 @@ def test_train_steps_spans(bert_args):
     hist = train.train_steps(tr, 3)
     steps = trace.spans("train.step")
     assert [s.step for s in steps] == [0, 1, 2] and tr.step == 3
-    for name in ("train.input", "train.to_device", "train.dispatch",
-                 "train.wait", "train.fetch"):
+    for name in ("train.dispatch", "train.wait", "train.fetch"):
         got = trace.spans(name)
         assert [s.parent for s in got] == steps, name
+    # input staged one step ahead: one batch a step, batch 0 inside step 0
+    # before its compile and dispatch, batch i+1 inside step i between its
+    # dispatch and its wait
+    dispatch, wait = trace.spans("train.dispatch"), trace.spans("train.wait")
     compile_span, = trace.spans("train.compile")
+    for name in ("train.input", "train.to_device"):
+        got = trace.spans(name)
+        assert [s.parent for s in got] == [steps[0]] + steps[:-1], name
+        assert got[0].end_ns <= compile_span.start_ns, name
+        for s, d, w in zip(got[1:], dispatch, wait):
+            assert d.end_ns <= s.start_ns <= s.end_ns <= w.start_ns, name
     assert compile_span.parent is steps[0]
     assert tr.compile_s == compile_span.seconds
     assert compile_span.compiles == 1
@@ -134,6 +143,10 @@ def test_train_steps_spans(bert_args):
     train.train_steps(tr, 2)                  # compiled already: no compile
     assert len(trace.spans("train.compile")) == 1
     assert [s.step for s in trace.spans("train.step")] == [0, 1, 2, 3, 4]
+    # the new call stages its own two batches, both inside its first step
+    step3 = trace.spans("train.step")[3]
+    for name in ("train.input", "train.to_device"):
+        assert [s.parent for s in trace.spans(name)[3:]] == [step3, step3]
     assert sum(s.compiles for s in trace.spans("train.step")[3:]) == 0
 
 
