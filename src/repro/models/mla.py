@@ -14,12 +14,12 @@ Per token x (d_model):
     k          = [k_nope | k_r], q = [q_nope | q_rope]: nope + rope wide
     out        = W_o softmax(q k^T / sqrt(nope + rope), causal) v
 
-Keys and queries are nope + rope (192) wide and values v_head_dim (128),
-so attention runs through the training kernel's two widths
-(kernels/flash_attention_kernel.py) where `attention._flash_fits` takes
-it, else through the XLA path. W_kv_a, its norm and W_kv_b run in the
-named scope `mla.latent`, inside `attention`. There is no decode cache:
-the mixer trains only.
+Keys and queries are nope + rope (192) wide and values v_head_dim (128).
+`attention.attend` attends them and chooses how, as for every other
+training attention: the training kernel, which takes the two widths
+(kernels/flash_attention_kernel.py), where it fits, else the XLA path.
+W_kv_a, its norm and W_kv_b run in the named scope `mla.latent`, inside
+`attention`. There is no decode cache: the mixer trains only.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.attention import _attend_block, _flash_attend, _flash_fits
+from repro.models.attention import attend
 from repro.models.common import (apply_rope, dense_apply, dense_init,
                                  rmsnorm_apply, rmsnorm_init)
 
@@ -100,12 +100,6 @@ def mla_apply(p, cfg: MlaConfig, x, *, positions=None, cache=None,
         [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, h, rope))], axis=-1)
     v = kv[..., nope:]
 
-    scale = 1.0 / math.sqrt(nope + rope)
-    if positions.ndim == 1 and _flash_fits(q):
-        out = _flash_attend(q, k, v, causal=True, scale=scale)
-    else:
-        out = _attend_block(q, positions, k=k, v=v, k_pos=positions,
-                            kv_len=None, scale=scale, causal=True,
-                            window=None, logit_softcap=None, decode=False,
-                            compute_dtype=compute_dtype)
+    out = attend(q, k, v, positions, causal=True, compute_dtype=compute_dtype,
+                 scale=1.0 / math.sqrt(nope + rope))
     return dense_apply(p["wo"], out.reshape(B, S, h * vd), compute_dtype), None

@@ -1,5 +1,6 @@
 """The training attention kernel (models/attention._flash_attend) against the
-XLA path it replaces, and the rule that chooses between them.
+XLA path it replaces, and the rule in `attention.attend` that chooses
+between them for attn_apply and mla_apply.
 
 The kernel runs in Pallas interpret mode here (`_flash_attend` interprets
 off a TPU); tests/test_tpu_compile.py compiles it for a described TPU.
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models import attention
+from repro.models import attention, mla
 from repro.models.attention import (AttnConfig, attn_apply, attn_init,
                                     init_kv_cache)
 
@@ -59,8 +60,8 @@ def _qkvw(B, S, h, kv, shared, dk=HD, dv=HD):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_flash_matches_xla_path(case):
-    """Output and dQ, dK, dV of the kernel path and of `_attend_block` on
-    the same bfloat16 inputs, against `_attend_block` in float32: the
+    """Output and dQ, dK, dV of the kernel path and of `attend`'s XLA path
+    on the same bfloat16 inputs, against the XLA path in float32: the
     kernel is within TOL of it, and no further from it than the XLA path
     is (plus one bfloat16 epsilon)."""
     B, S, h, kv, causal, block, shared = CASES[case]
@@ -69,14 +70,12 @@ def test_flash_matches_xla_path(case):
     pos = jnp.arange(S)
     scale = dk ** -0.5
 
-    def rep(a):  # GQA as attn_apply does it: its transpose sums each group
+    def rep(a):  # GQA as attend does it: its transpose sums each group
         return jnp.repeat(a, h // kv, axis=2)
 
-    def xla(q, k, v):
-        return attention._attend_block(
-            q, pos, k=rep(k), v=rep(v), k_pos=pos, kv_len=None, scale=scale,
-            causal=causal, window=None, logit_softcap=None, decode=False,
-            compute_dtype=q.dtype)
+    def xla(q, k, v):  # off a TPU, attend takes the XLA path
+        return attention.attend(q, k, v, pos, causal=causal, scale=scale,
+                                compute_dtype=q.dtype)
 
     def flash(q, k, v):
         return attention._flash_attend(q, rep(k), rep(v), causal=causal,
@@ -109,38 +108,62 @@ def _x(S):
     return jnp.zeros((B, S, D_MODEL), jnp.bfloat16)
 
 
-# name: (takes the kernel, cfg overrides, S, extra attn_apply kwargs (by S))
+def _attn(over=None, extra=lambda S: {}):
+    """attn_apply at length S with cfg overrides and extra kwargs (by S)."""
+    def build(S):
+        cfg = _cfg(**(over or {}))
+        params = attn_init(jax.random.PRNGKey(0), cfg)
+        return functools.partial(attn_apply, params, cfg,
+                                 compute_dtype=jnp.bfloat16, **extra(S))
+    return build
+
+
+def _mla(S):
+    """mla_apply: keys 2 * HD wide (half of them rotary), values HD."""
+    del S
+    cfg = mla.MlaConfig(d_model=D_MODEL, n_heads=HEADS, kv_lora_rank=HD,
+                        qk_nope_head_dim=HD, qk_rope_head_dim=HD,
+                        v_head_dim=HD)
+    params = mla.mla_init(jax.random.PRNGKey(0), cfg)
+    return functools.partial(mla.mla_apply, params, cfg,
+                             compute_dtype=jnp.bfloat16)
+
+
+# name: (takes the kernel, S, the attention at length S); every case but
+# the *cpu_backend ones runs with the backend patched to a TPU
 SELECTION = {
-    "training_self_attention": (True, {}, 512, lambda S: {}),
-    "bidirectional": (True, {"causal": False}, 512, lambda S: {}),
-    "long_causal": (True, {}, 2048, lambda S: {}),
-    "cache": (False, {}, 512, lambda S: {
-        "cache": init_kv_cache(B, S, KV_HEADS, HD)}),
-    "kv_x": (False, {"causal": False}, 512, lambda S: {"kv_x": _x(S)}),
-    "kv_cache": (False, {"causal": False}, 512, lambda S: {"kv_cache": {
-        "k": jnp.zeros((B, S, KV_HEADS, HD), jnp.bfloat16),
-        "v": jnp.zeros((B, S, KV_HEADS, HD), jnp.bfloat16)}}),
-    "positions_2d": (False, {}, 512, lambda S: {
-        "positions": jnp.broadcast_to(jnp.arange(S), (B, S))}),
-    "softcap": (False, {"logit_softcap": 50.0}, 512, lambda S: {}),
-    "sliding_window": (False, {"sliding_window": 128}, 512, lambda S: {}),
-    "off_block_grid": (False, {}, 1536, lambda S: {}),
-    "below_threshold": (False, {}, attention.FLASH_MIN_SEQ // 2,
-                        lambda S: {}),
-    "cpu_backend": (False, {}, 512, lambda S: {}),
+    "training_self_attention": (True, 512, _attn()),
+    "bidirectional": (True, 512, _attn({"causal": False})),
+    "long_causal": (True, 2048, _attn()),
+    "cache": (False, 512, _attn(extra=lambda S: {
+        "cache": init_kv_cache(B, S, KV_HEADS, HD)})),
+    "kv_x": (False, 512, _attn({"causal": False},
+                               lambda S: {"kv_x": _x(S)})),
+    "kv_cache": (False, 512, _attn({"causal": False}, lambda S: {
+        "kv_cache": {"k": jnp.zeros((B, S, KV_HEADS, HD), jnp.bfloat16),
+                     "v": jnp.zeros((B, S, KV_HEADS, HD), jnp.bfloat16)}})),
+    "positions_2d": (False, 512, _attn(extra=lambda S: {
+        "positions": jnp.broadcast_to(jnp.arange(S), (B, S))})),
+    "softcap": (False, 512, _attn({"logit_softcap": 50.0})),
+    "sliding_window": (False, 512, _attn({"sliding_window": 128})),
+    "off_block_grid": (False, 1536, _attn()),
+    "below_threshold": (False, attention.FLASH_MIN_SEQ // 2, _attn()),
+    "cpu_backend": (False, 512, _attn()),
+    # a ring cache shorter than the prompt (a sliding-window layer's
+    # prefill): the in-flight keys are attended, through XLA
+    "ring_prefill": (False, 512, _attn({"sliding_window": 128}, lambda S: {
+        "cache": init_kv_cache(B, 128, KV_HEADS, HD)})),
+    "mla": (True, 512, _mla),
+    "mla_cpu_backend": (False, 512, _mla),
 }
 
 
 @pytest.mark.parametrize("case", sorted(SELECTION))
 def test_flash_path_selection(case, monkeypatch):
-    """attn_apply traces the kernel for training-shaped self-attention on a
-    TPU, and the XLA path in every other case."""
-    takes, over, S, extra = SELECTION[case]
-    if case != "cpu_backend":
+    """attn_apply and mla_apply trace the kernel for training-shaped
+    self-attention on a TPU, and the XLA path in every other case."""
+    takes, S, build = SELECTION[case]
+    if not case.endswith("cpu_backend"):
         monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    cfg = _cfg(**over)
-    params = attn_init(jax.random.PRNGKey(0), cfg)
-    jaxpr = jax.make_jaxpr(functools.partial(
-        attn_apply, params, cfg, compute_dtype=jnp.bfloat16, **extra(S)))(
-            _x(S))
+    jaxpr = jax.make_jaxpr(build(S))(_x(S))
     assert ("pallas_call" in str(jaxpr)) == takes

@@ -276,22 +276,23 @@ def test_throughput_probe_excludes_compile():
     assert stats["wall_s"] < 5.0
 
 
-def test_chunked_attention_accepts_per_batch_positions():
+def test_chunked_attention_accepts_per_batch_positions(monkeypatch):
     """Regression (review finding): the remat-chunked query-block path must
     handle 2-D (B, S) positions — a batched left-padded serving prefill
-    long enough to trip q_chunk_threshold used to crash on the reshape."""
-    import dataclasses
+    long enough to trip Q_CHUNK_MIN_SEQ used to crash on the reshape."""
     import jax.numpy as jnp
+    from repro.models import attention
     from repro.models.attention import AttnConfig, attn_apply, attn_init
-    cfg = AttnConfig(d_model=32, n_heads=2, n_kv_heads=2, head_dim=16,
-                     q_chunk_threshold=8, q_block=4)
-    ref_cfg = dataclasses.replace(cfg, q_chunk_threshold=10 ** 9)
+    cfg = AttnConfig(d_model=32, n_heads=2, n_kv_heads=2, head_dim=16)
     p = attn_init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32), jnp.float32)
     pos = jnp.stack([jnp.arange(8) - 3, jnp.arange(8)])  # row 0 left-padded
+    monkeypatch.setattr(attention, "Q_CHUNK_MIN_SEQ", 10 ** 9)
+    out_ref, _ = attn_apply(p, cfg, x, positions=pos,
+                            compute_dtype=jnp.float32)
+    monkeypatch.setattr(attention, "Q_CHUNK_MIN_SEQ", 8)
+    monkeypatch.setattr(attention, "Q_BLOCK", 4)
     out_chunked, _ = attn_apply(p, cfg, x, positions=pos,
                                 compute_dtype=jnp.float32)
-    out_ref, _ = attn_apply(p, ref_cfg, x, positions=pos,
-                            compute_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out_chunked), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
