@@ -1,12 +1,16 @@
 """Mixture-of-Experts FFN with top-k routing (grok-1, granite-moe, jamba).
 
-GShard/Mesh-TF style dense dispatch: tokens are routed to experts through a
-capacity-bounded one-hot dispatch tensor and combined back with router
-probabilities. On the production mesh the expert dimension is sharded over
-the "model" axis (expert parallelism) so the two dispatch einsums lower to
-all-to-all-like collectives — exactly the communication pattern MoE papers
-optimize, and the place LANS's per-block trust ratios matter most (router
-blocks see very different gradient scales than expert FFN blocks).
+Capacity-bounded dispatch: each (token, k) pair routed to an expert takes
+the next of that expert's `cap` slots in token order, or is dropped when
+they are full. One map between pairs and slots moves the tokens: dispatch
+gathers each slot's token row into dense (E, cap, d) expert buffers,
+combine gathers each pair's expert output back and weights it by its
+router probability. No scatter moves a row, forward or backward: the map
+is a bijection between kept pairs and filled slots, so each adjoint is a
+gather through the inverse map. On the production mesh the expert
+dimension is sharded over the "model" axis (expert parallelism). Expert
+layers are where LANS's per-block trust ratios matter most (router blocks
+see very different gradient scales than expert FFN blocks).
 
 Includes the standard auxiliary load-balancing loss (Shazeer et al.) exposed
 to the training loop.
@@ -81,12 +85,6 @@ def moe_init(rng, cfg: MoeConfig, dtype=jnp.float32):
     return p
 
 
-def _top_k_mask(probs: jnp.ndarray, k: int):
-    """(T, E) probs -> (T, E) bool mask of the per-token top-k experts."""
-    _, idx = jax.lax.top_k(probs, k)  # (T, k)
-    return jax.nn.one_hot(idx, probs.shape[-1], dtype=bool).any(axis=-2)
-
-
 def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
     """x: (B, S, d). Returns (out, aux) with aux = load-balance loss terms.
 
@@ -96,15 +94,19 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
     cumsum), produce zero output, and drop out of the load-balance stats,
     so real tokens route identically to a pad-free run.
 
-    GROUP-LOCAL SCATTER DISPATCH. The classic GShard one-hot dispatch
+    GROUP-LOCAL GATHER DISPATCH. The classic GShard one-hot dispatch
     materializes a (T, E, C) tensor — O(T^2 K / E) memory/FLOPs, which blew
-    the granite-40e configs to 5.5 TB at prefill_32k (EXPERIMENTS.md §Perf
-    iteration 1). Instead:
+    the granite-40e configs to 5.5 TB at prefill_32k. Instead:
       1. tokens are split into G groups (G = ambient "data" axis size) and
          routed group-locally — each group enforces its own capacity, which
          is exactly what per-device routing does in production MoE systems;
-      2. dispatch is a scatter-add into (G, E, C_local, d) expert buffers
-         and combine is a gather — O(T*K*d + G*E*C_local*d), no TEC tensor.
+      2. one map links pairs and slots: pair -> slot from the cumsum
+         position, slot -> pair from a stable sort of the pairs by expert
+         (token order within an expert, as the position counts);
+      3. dispatch gathers each slot's token row into (G, E, C_local, d)
+         expert buffers, combine gathers each pair's output row; the
+         adjoint of each gather is a gather through the inverse map
+         (`_move`) — O(E*C_local*d + T*K*d), no TEC tensor, no scatter.
     Expert compute stays dense einsum (MXU): experts over "model" when
     divisible (jamba 16e), otherwise the ff dim (grok 8e, granite 40e).
     """
@@ -144,41 +146,44 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
 
         # Held experts' index of each (token, k); a pair sent to an expert
         # held elsewhere gets no one-hot, so it takes no place below.
-        held = None
         if partial:
             local = idx_k - cfg.first_expert
-            held = (local >= 0) & (local < E)
-            idx_k = jnp.where(held, local, -1)
+            idx_k = jnp.where((local >= 0) & (local < E), local, -1)
         # Position of each (token, k) within its expert's buffer, per group.
         sel = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)      # (G, Tl, K, E)
         if partial:
             idx_k = jnp.maximum(idx_k, 0)
-        vflat = None
         if valid is not None:
             vg = valid.reshape(G, Tl)
             sel = sel * vg[..., None, None].astype(sel.dtype)  # pads: nowhere
-            vflat = jnp.repeat(vg, K, axis=1)                # (G, TlK)
         sel_flat = sel.reshape(G, Tl * K, E)
+        placed = sel_flat.sum(axis=-1) > 0           # pairs that take a place
         position = jnp.cumsum(sel_flat, axis=1) - 1          # (G, TlK, E)
-        pos_k = jnp.take_along_axis(
-            position, idx_k.reshape(G, Tl * K)[..., None], axis=-1)[..., 0]
-        keep = pos_k < cap                                   # (G, TlK)
-        if vflat is not None:
-            keep = keep & vflat
-        if held is not None:
-            keep = keep & held.reshape(G, Tl * K)
-        pos_clipped = jnp.where(keep, pos_k, cap)            # overflow bucket
-
-    # Scatter dispatch: (G, E, cap+1, d), drop the overflow bucket after.
-    with jax.named_scope("moe.dispatch"):
         flat_e = idx_k.reshape(G, Tl * K)
-        x_rep = jnp.repeat(xg.astype(compute_dtype), K, axis=1)  # (G, TlK, d)
+        pos_k = jnp.take_along_axis(
+            position, flat_e[..., None], axis=-1)[..., 0]
+        keep = placed & (pos_k < cap)                        # (G, TlK)
 
-        def scatter_group(xr, e_idx, p_idx):
-            buf = jnp.zeros((E, cap + 1, d), compute_dtype)
-            return buf.at[e_idx, p_idx].add(xr)
+        # The pair <-> slot map; an index past the end stands for none.
+        # pair -> slot: row e * cap + position of the (E * cap, d) buffer.
+        slot_of = jnp.where(keep, flat_e * cap + pos_k, E * cap)
+        # slot -> pair: a stable sort by expert lists each expert's placed
+        # pairs in token order, so slot (e, c) holds the c-th of them.
+        order = jnp.argsort(jnp.where(placed, flat_e, E), axis=1,
+                            stable=True)
+        count = sel_flat.sum(axis=1)                         # (G, E)
+        c = jnp.arange(cap)
+        at = (jnp.cumsum(count, axis=1) - count)[..., None] + c
+        filled = c < count[..., None]                        # (G, E, cap)
+        pair_of = jnp.take_along_axis(
+            order, jnp.minimum(at, Tl * K - 1).reshape(G, E * cap), axis=1)
+        pair_of = jnp.where(filled.reshape(G, E * cap), pair_of, Tl * K)
 
-        xin = jax.vmap(scatter_group)(x_rep, flat_e, pos_clipped)[:, :, :cap]
+    # Dispatch: each slot's token row, zero where the slot is empty.
+    with jax.named_scope("moe.dispatch"):
+        xin = jax.vmap(_move)(xg.astype(compute_dtype), pair_of // K,
+                              slot_of.reshape(G, Tl, K))
+        xin = xin.reshape(G, E, cap, d)
         ep = E % max(ambient_axis_size("model"), 1) == 0 \
             and ambient_axis_size("model") > 1
         e_ax = "model" if ep else None
@@ -202,10 +207,8 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
 
     # Combine: gather each (token, k)'s expert output, weight, sum over K.
     with jax.named_scope("moe.combine"):
-        yflat = yout.reshape(G, E * cap, d)
-        gather_idx = jnp.minimum(
-            flat_e * cap + jnp.minimum(pos_clipped, cap - 1), E * cap - 1)
-        y_tk = jnp.take_along_axis(yflat, gather_idx[..., None], axis=1)
+        y_tk = jax.vmap(_move)(yout.reshape(G, E * cap, d), slot_of,
+                               pair_of[..., None])
         w = (gates_k.reshape(G, Tl * K).astype(compute_dtype)
              * keep.astype(compute_dtype))
         out = (y_tk * w[..., None]).reshape(G, Tl, K, d).sum(axis=2)
@@ -234,6 +237,27 @@ def moe_apply(p, cfg: MoeConfig, x, *, valid=None, compute_dtype=jnp.bfloat16):
         entropy = -(probs * jnp.log(probs + 1e-9)).sum(-1).mean()
     return out.astype(x.dtype), {"moe_aux_loss": aux_loss,
                                  "router_entropy": entropy}
+
+
+@jax.custom_vjp
+def _move(src, idx, inv):
+    """Rows of src (N, d) by idx (n,): out[i] = src[idx[i]], zero where
+    idx[i] >= N. inv (N, m) is the inverse map: out's rows that take src
+    row r, >= n for none. The adjoint sums d(out) over inv's rows, a
+    gather where autodiff of the gather would scatter."""
+    return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _move_fwd(src, idx, inv):
+    return _move(src, idx, inv), inv
+
+
+def _move_bwd(inv, d_out):
+    d_src = jnp.take(d_out, inv, axis=0, mode="fill", fill_value=0)
+    return d_src.sum(axis=1), None, None
+
+
+_move.defvjp(_move_fwd, _move_bwd)
 
 
 def _sequence_balance(scores, chosen, R: int, K: int):

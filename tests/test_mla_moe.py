@@ -10,6 +10,12 @@ expert), so they agree to float32 rounding: relative 1e-5 on a loss, and
 a few hundred terms cancel (the bench's own reference tests use the same).
 """
 import dataclasses
+import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +28,10 @@ from bench.reference.numerics import init_params, matmul
 from repro.configs import reduced_arch
 from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
+from repro.models.common import (ACTIVATIONS, ambient_axis_size,
+                                 maybe_constrain, mlp_apply)
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 LOSS_RTOL = 1e-5
 GRAD_RTOL, GRAD_ATOL = 5e-4, 1e-6
@@ -187,3 +197,224 @@ def test_small_moonlight_loss_and_grads_match_reference(aux_weight):
             lambda p: ref.objective(p, model, batch, MM)))(params)
     np.testing.assert_allclose(got, want, rtol=LOSS_RTOL)
     _close(got_g, want_g)
+
+
+def _scatter_moe_apply(p, cfg, x, *, valid=None, compute_dtype=jnp.bfloat16):
+    """The expert layer as it was before dispatch and combine became gathers
+    through a pair <-> slot map: every token repeated K times and
+    scatter-added into (G, E, cap + 1, d) buffers, dropped pairs into the
+    last row, and combine a gather whose adjoint is a scatter. Returns
+    (out, aux, groups, dropped pairs)."""
+    B, S, d = x.shape
+    E, K, R = cfg.n_experts, cfg.top_k, cfg.n_routed
+    partial = R != E or cfg.first_expert != 0
+    T = B * S
+    dp_axes = tuple(a for a in ("pod", "data") if ambient_axis_size(a) > 1)
+    G = max(1, ambient_axis_size("pod") * ambient_axis_size("data"))
+    while T % G != 0:
+        G //= 2
+    G = max(G, 1)
+    Tl = T // G
+    cap = max(1, int(cfg.capacity_factor * Tl * K / R))
+    xg = maybe_constrain(x.reshape(G, Tl, d), dp_axes or None, None, None)
+
+    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
+                        p["router"]["kernel"])
+    if cfg.router == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, idx_k = jax.lax.top_k(probs + p["router"]["bias"], K)
+        gates_k = jnp.take_along_axis(probs, idx_k, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates_k, idx_k = jax.lax.top_k(probs, K)
+    chosen = idx_k
+    gates_k = gates_k / jnp.maximum(gates_k.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        gates_k = gates_k * cfg.routed_scaling
+    held = None
+    if partial:
+        local = idx_k - cfg.first_expert
+        held = (local >= 0) & (local < E)
+        idx_k = jnp.where(held, local, -1)
+    sel = jax.nn.one_hot(idx_k, E, dtype=jnp.int32)
+    if partial:
+        idx_k = jnp.maximum(idx_k, 0)
+    vflat = None
+    if valid is not None:
+        vg = valid.reshape(G, Tl)
+        sel = sel * vg[..., None, None].astype(sel.dtype)
+        vflat = jnp.repeat(vg, K, axis=1)
+    sel_flat = sel.reshape(G, Tl * K, E)
+    position = jnp.cumsum(sel_flat, axis=1) - 1
+    pos_k = jnp.take_along_axis(
+        position, idx_k.reshape(G, Tl * K)[..., None], axis=-1)[..., 0]
+    keep = pos_k < cap
+    placed = jnp.ones_like(keep)
+    if vflat is not None:
+        placed = placed & vflat
+    if held is not None:
+        placed = placed & held.reshape(G, Tl * K)
+    dropped = jnp.sum(placed & ~keep)
+    keep = keep & placed
+    pos_clipped = jnp.where(keep, pos_k, cap)
+
+    flat_e = idx_k.reshape(G, Tl * K)
+    x_rep = jnp.repeat(xg.astype(compute_dtype), K, axis=1)
+
+    def scatter_group(xr, e_idx, p_idx):
+        buf = jnp.zeros((E, cap + 1, d), compute_dtype)
+        return buf.at[e_idx, p_idx].add(xr)
+
+    xin = jax.vmap(scatter_group)(x_rep, flat_e, pos_clipped)[:, :, :cap]
+    act = ACTIVATIONS[cfg.activation]
+    up = jnp.einsum("gecd,edf->gecf", xin, p["up"].astype(compute_dtype))
+    if cfg.gated:
+        up = act(jnp.einsum("gecd,edf->gecf", xin,
+                            p["gate"].astype(compute_dtype))) * up
+    else:
+        up = act(up)
+    yout = jnp.einsum("gecf,efd->gecd", up, p["down"].astype(compute_dtype))
+    yflat = yout.reshape(G, E * cap, d)
+    gather_idx = jnp.minimum(
+        flat_e * cap + jnp.minimum(pos_clipped, cap - 1), E * cap - 1)
+    y_tk = jnp.take_along_axis(yflat, gather_idx[..., None], axis=1)
+    w = (gates_k.reshape(G, Tl * K).astype(compute_dtype)
+         * keep.astype(compute_dtype))
+    out = (y_tk * w[..., None]).reshape(G, Tl, K, d).sum(axis=2)
+    out = out.reshape(B, S, d)
+    if cfg.n_shared:
+        out = out + mlp_apply(p["shared"], x, activation=cfg.activation,
+                              compute_dtype=compute_dtype)
+    if cfg.seq_aux:
+        aux_loss = moe_lib._sequence_balance(
+            probs, chosen.reshape(B, S, K), R, K)
+        probs = probs / probs.sum(-1, keepdims=True)
+    else:
+        if partial:
+            topk_mask = jax.nn.one_hot(chosen, R, dtype=jnp.int32
+                                       ).sum(axis=2) > 0
+        else:
+            topk_mask = sel.sum(axis=2) > 0
+        density = topk_mask.astype(jnp.float32).mean(axis=(0, 1))
+        aux_loss = jnp.sum(density * probs.mean(axis=(0, 1))) * (R / K)
+    entropy = -(probs * jnp.log(probs + 1e-9)).sum(-1).mean()
+    aux = {"moe_aux_loss": aux_loss, "router_entropy": entropy}
+    return out.astype(x.dtype), aux, G, dropped
+
+
+def _softmax_moe(E=16, K=8, capacity=0.5):
+    return moe_lib.MoeConfig(d_model=32, d_ff=16, n_experts=E, top_k=K,
+                             capacity_factor=capacity)
+
+
+# name: (config, (B, S), left pads per sequence or None, data mesh size)
+DISPATCH_CASES = {
+    "softmax-top8-drops": (_softmax_moe(), (2, 24), None, 1),
+    "sigmoid-top6-held-8-of-64": (_moe(R=64, E=8, first=16, K=6),
+                                  (2, 24), None, 1),
+    "valid-pads": (_softmax_moe(E=8, K=2, capacity=0.75), (3, 16),
+                   (0, 5, 11), 1),
+    "groups-2-data-mesh": (_softmax_moe(), (4, 12), (0, 3, 0, 7), 2),
+}
+
+
+def _dispatch_case(name):
+    """The layer and the scatter reference on one case, in float32: both
+    outputs, aux terms and gradients of a weighted sum of the output plus
+    the aux terms by every weight and the input; the reference's groups and
+    dropped pairs."""
+    cfg, (B, S), pads, data = DISPATCH_CASES[name]
+    p = _moe_params(cfg, seed=11)
+    if cfg.router == "sigmoid":   # crowd the held experts, so some drop
+        p["router"]["kernel"] = p["router"]["kernel"].at[:, 16:24].add(0.4)
+    x = jax.random.normal(jax.random.PRNGKey(12), (B, S, 32))
+    wt = jax.random.normal(jax.random.PRNGKey(13), (B, S, 32))
+    valid = None
+    if pads is not None:
+        valid = jnp.arange(S)[None, :] >= jnp.asarray(pads)[:, None]
+
+    def objective(f):
+        def fn(p, x):
+            out, aux = f(p, cfg, x, valid=valid,
+                         compute_dtype=jnp.float32)[:2]
+            return (jnp.sum(out * wt) + aux["moe_aux_loss"]
+                    + aux["router_entropy"]), (out, aux)
+        return jax.jit(jax.grad(fn, argnums=(0, 1), has_aux=True))
+
+    from repro.launch.mesh import make_local_mesh
+    with make_local_mesh(data=data, model=1), \
+            jax.default_matmul_precision("highest"):
+        got = objective(moe_lib.moe_apply)(p, x)
+        want = objective(_scatter_moe_apply)(p, x)
+        _, _, groups, dropped = jax.jit(
+            lambda p, x: _scatter_moe_apply(p, cfg, x, valid=valid,
+                                            compute_dtype=jnp.float32)
+        )(p, x)
+    return got, want, int(groups), int(dropped)
+
+
+def _same(got, want):
+    """Gathers move the very rows the scatters moved, and every sum runs
+    over the same terms in the same order: the numbers are equal."""
+    got, want = paths_of(got), paths_of(want)
+    assert got.keys() == want.keys()
+    for p in want:
+        np.testing.assert_array_equal(np.asarray(got[p]),
+                                      np.asarray(want[p]), err_msg=p)
+
+
+@pytest.mark.parametrize("case", list(DISPATCH_CASES))
+def test_gather_dispatch_matches_scatter_reference(case):
+    """Dispatch and combine through the pair <-> slot map give the scatter
+    formulation's output, aux terms and gradients: with pairs dropped past
+    capacity, a held-expert share of a sigmoid router, left pads, and two
+    groups on a two-device data mesh (a process of its own, its CPU split
+    in two devices)."""
+    data = DISPATCH_CASES[case][3]
+    if data > 1 and jax.device_count() < data:
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       filter(None, [str(ROOT / "src"), str(ROOT),
+                                     os.environ.get("PYTHONPATH")])),
+                   XLA_FLAGS=" ".join(filter(None, [
+                       os.environ.get("XLA_FLAGS"),
+                       f"--xla_force_host_platform_device_count={data}"])))
+        proc = subprocess.run([sys.executable, __file__, case], env=env,
+                              cwd=ROOT, capture_output=True, text=True,
+                              timeout=600)
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        groups, dropped = json.loads(proc.stdout.strip().splitlines()[-1])
+    else:
+        got, want, groups, dropped = _dispatch_case(case)
+        _same(got, want)
+    assert groups == data
+    assert dropped > 0
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-3b-a800m", "moonlight-16b-a3b"])
+def test_train_step_moves_expert_rows_without_scatter(arch):
+    """The compiled train step of each expert config has no scatter under
+    `moe.dispatch` or `moe.combine` in any pass (forward, recompute,
+    backward), while both scopes still hold ops in each."""
+    from bench import scopes
+    from repro.launch import train
+
+    args = train.build_parser().parse_args(
+        ["--arch", arch, "--batch", "2", "--seq", "16", "--precision", "bf16"])
+    tr = train.make_trainer(args)
+    train.train_steps(tr, 1)
+    hlo = tr.compiled.as_text()
+    scatters = [line for line in hlo.splitlines()
+                if re.search(r"=\s*\S+\s+scatter\(", line)
+                and re.search(r'op_name="[^"]*moe\.(dispatch|combine)', line)]
+    assert scatters == []
+    found = set(scopes.scope_map(hlo).values())
+    for s in ("moe.dispatch", "moe.combine"):
+        assert {"forward", "recompute", "backward"} <= {
+            k for t, k in found if t == s}
+
+
+if __name__ == "__main__":
+    got, want, groups, dropped = _dispatch_case(sys.argv[1])
+    _same(got, want)
+    print(json.dumps([groups, dropped]))
